@@ -231,7 +231,7 @@ def _mixed_precision_rows(shape) -> list:
     """f32-inner refinement vs pure f64: same tolerance, fewer f64 ops."""
     rows: list[Row] = []
     tol = 1e-10
-    from jax.experimental import enable_x64
+    from repro.compat import enable_x64
 
     with enable_x64():
         Ue, Uo, e, o = _rand_eo(shape, seed=9)

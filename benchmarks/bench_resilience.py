@@ -119,7 +119,7 @@ def _nan_recovery_rows(shape) -> list:
 
 
 def _escalation_rows(shape) -> list:
-    from jax.experimental import enable_x64
+    from repro.compat import enable_x64
 
     with enable_x64():
         Ue, Uo, e, o = _fields(shape, dtype=jnp.complex128)

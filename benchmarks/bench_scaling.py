@@ -82,6 +82,9 @@ def run() -> list:
         env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={n} "
                             + env.get("XLA_FLAGS", ""))
         env["PYTHONPATH"] = str(repo / "src")
+        # The children are forced-host-device CPU experiments: keep them
+        # off an accelerator, which this process may already hold.
+        env["JAX_PLATFORMS"] = "cpu"
         out = subprocess.run([sys.executable, "-c",
                               textwrap.dedent(_CHILD)],
                              capture_output=True, text=True, env=env,

@@ -21,8 +21,9 @@ can see:
   :func:`~repro.kernels.wilson_stencil.stream_ring_bytes`,
   :func:`~repro.kernels.wilson_stencil.fused_dhat_policy`,
   :func:`~repro.kernels.wilson_stencil.dhat_stream_traffic_model`)
-  agree with an independently-computed byte count, switch exactly at
-  the 12 MiB budget boundary, and the stream ring is T-independent.
+  agree with an independently-computed count of the tiled VMEM the
+  TPU compiler allocates, switch exactly at the per-kernel limit, and
+  the stream ring is T-independent.
 * **J4 — retrace budget.**  A replayed :class:`repro.api.SolveSession`
   scenario (repeat solves, a shape change, a spec change) performs
   exactly as many traces as distinct cache keys — the bind-once
@@ -134,7 +135,7 @@ def _walk_eqns(jaxpr):
     count each *launch*, not each distinct kernel body.  Jaxprs are
     acyclic, so per-reference traversal terminates.
     """
-    from jax import core as jcore
+    from jax.extend import core as jcore
 
     agenda = [jaxpr]
     while agenda:
@@ -149,7 +150,7 @@ def _walk_eqns(jaxpr):
 
 
 def _as_jaxprs(val):
-    from jax import core as jcore
+    from jax.extend import core as jcore
 
     if isinstance(val, (jcore.Jaxpr, jcore.ClosedJaxpr)):
         yield val
@@ -295,8 +296,12 @@ def check_vmem_model(root: str, *,
                      limit_bytes: Optional[int] = None) -> List[Finding]:
     """J3: the policy's byte math agrees with an independent estimate.
 
-    The override hooks substitute any one estimator so the self-tests
-    can seed an inconsistency (e.g. a policy that streams too early).
+    The estimate here recounts what the TPU compiler allocates: every
+    ``(Y, Xh)`` plane padded to whole (sublane, 128-lane) tiles, the
+    double-buffered pipeline blocks read off each fused kernel's specs,
+    and the body spills, against the per-kernel VMEM limit.  The
+    override hooks substitute any one estimator so the self-tests can
+    seed an inconsistency (e.g. a policy that streams too early).
     """
     import jax.numpy as jnp
     from repro.kernels import wilson_stencil as ws
@@ -304,27 +309,54 @@ def check_vmem_model(root: str, *,
     fits = fits_fn or ws.fused_dhat_fits
     ring = ring_fn or ws.stream_ring_bytes
     policy = policy_fn or ws.fused_dhat_policy
-    limit = (ws._FUSED_SCRATCH_LIMIT_BYTES
-             if limit_bytes is None else limit_bytes)
+    limit = ws._VMEM_LIMIT_BYTES if limit_bytes is None else limit_bytes
     window = ws.STREAM_WINDOW_ROWS
     findings: List[Finding] = []
 
-    def plane_elems(shape):
-        # Elements of one t-plane of the (possibly batched) planar
-        # intermediate: everything except the T axis.
-        if len(shape) == 6:          # (nrhs, T, Z, 24, Y, Xh)
-            nrhs, _, Z, C, Y, Xh = shape
-        else:                        # (T, Z, 24, Y, Xh)
-            _, Z, C, Y, Xh = shape
-            nrhs = 1
-        return nrhs * Z * C * Y * Xh
+    def tiled_plane(Y, Xh, itemsize):
+        rows = 8 * (4 // itemsize) if itemsize < 4 else 8
+        return (itemsize * ((Y + rows - 1) // rows * rows)
+                * ((Xh + 127) // 128 * 128))
+
+    # (spinor blocks, gauge plane-sets) per grid step, from the kernels'
+    # specs: resident = 5 psi views + out, u_out + z/t views per parity;
+    # stream = 6 psi views + out, 4 quartets + 4 shifted views.
+    blocks = {"resident": (6, 12), "stream": (7, 20)}
+
+    def budget(kernel, shape, itemsize, gc=18):
+        nrhs = shape[0] if len(shape) == 6 else 1
+        Y, Xh = shape[-2], shape[-1]
+        spin, sets = blocks[kernel]
+        pipeline = 2 * (spin * nrhs * 24 + sets * gc) * tiled_plane(
+            Y, Xh, itemsize)
+        spill = ws._BODY_SPILL_PLANES * nrhs * tiled_plane(
+            Y, Xh, max(itemsize, 4))
+        return limit - pipeline - spill
+
+    def planes(shape):
+        # Tiled planes of the (possibly batched) planar intermediate and
+        # of one of its t-rows: everything except (Y, Xh), and also T.
+        lead = 1 if len(shape) == 6 else 0
+        total = math.prod(shape[:-2])
+        return total, total // shape[lead]
+
+    def estimate(shape, itemsize, gc=18):
+        total, row = planes(shape)
+        plane = tiled_plane(shape[-2], shape[-1], itemsize)
+        resident, ringsz = total * plane, window * row * plane
+        res_ok = resident <= budget("resident", shape, itemsize, gc)
+        ring_ok = ringsz <= budget("stream", shape, itemsize, gc)
+        want = ("resident" if res_ok else
+                "stream" if ring_ok else "unfused")
+        return resident, ringsz, res_ok, want
 
     # Shapes straddling the budget: resident fits / only the ring fits /
     # nothing fits, plus exact-boundary rows for the <= vs < distinction.
-    T_at_limit = limit // (4 * 4 * 24 * 4 * 4)      # f32 (T,4,24,4,4)
+    row4 = 4 * 24 * tiled_plane(4, 4, 4)             # f32 (T,4,24,4,4)
+    T_at_limit = budget("resident", (1, 4, 24, 4, 4), 4) // row4
     cases = [
         (4, 4, 24, 4, 2), (8, 8, 24, 8, 4),
-        (T_at_limit, 4, 24, 4, 4),          # resident == limit exactly
+        (T_at_limit, 4, 24, 4, 4),          # last t-row that fits
         (T_at_limit + 1, 4, 24, 4, 4),      # one row over
         (4096, 8, 24, 8, 4),                # huge T: stream territory
         (2, 4096, 24, 64, 64),              # huge plane: unfused
@@ -333,15 +365,16 @@ def check_vmem_model(root: str, *,
     for shape in cases:
         for dtype in (jnp.float32, jnp.bfloat16):
             itemsize = jnp.dtype(dtype).itemsize
-            resident = itemsize * math.prod(shape)
-            ringsz = itemsize * window * plane_elems(shape)
+            resident, ringsz, res_ok, want_policy = estimate(
+                shape, itemsize)
 
-            if fits(shape, dtype) != (resident <= limit):
+            if fits(shape, dtype) != res_ok:
                 findings.append(_finding(
                     root, "J3",
                     f"fused_dhat_fits({shape}, {jnp.dtype(dtype).name}) "
                     f"disagrees with the independent estimate "
-                    f"{resident}B vs limit {limit}B"))
+                    f"{resident}B vs budget "
+                    f"{budget('resident', shape, itemsize)}B"))
             got_ring = ring(shape, dtype)
             if got_ring != ringsz:
                 findings.append(_finding(
@@ -349,9 +382,7 @@ def check_vmem_model(root: str, *,
                     f"stream_ring_bytes({shape}, "
                     f"{jnp.dtype(dtype).name}) = {got_ring}, "
                     f"independent estimate {ringsz} "
-                    f"({window} rows x {plane_elems(shape)} elems)"))
-            want_policy = ("resident" if resident <= limit else
-                           "stream" if ringsz <= limit else "unfused")
+                    f"({window} rows x {planes(shape)[1]} tiled planes)"))
             got_policy = policy(shape, dtype)
             if got_policy != want_policy:
                 findings.append(_finding(
@@ -370,15 +401,15 @@ def check_vmem_model(root: str, *,
 
     # Compressed-gauge headroom: storing 12/8 of 18 real link planes
     # frees VMEM in the double-buffered gauge window (12 plane-sets in
-    # flight per grid step); fits/policy must extend the scratch budget
-    # by exactly that headroom — and gauge_comps=18 must be a strict
-    # no-op, so every boundary above stays where it was.
+    # flight per grid step of the resident kernel); fits/policy must
+    # extend the scratch budget by exactly that headroom — and
+    # gauge_comps=18 must be a strict no-op on every boundary above.
     headroom = headroom_fn or ws.gauge_headroom_bytes
     for gc in (18, 12, 8):
         for dtype in (jnp.float32, jnp.bfloat16):
             Y, Xh = 4, 4
             itemsize = jnp.dtype(dtype).itemsize
-            want_head = (18 - gc) * 12 * 2 * Y * Xh * itemsize
+            want_head = (18 - gc) * 12 * 2 * tiled_plane(Y, Xh, itemsize)
             got_head = headroom(Y, Xh, itemsize, gauge_comps=gc)
             if got_head != want_head:
                 findings.append(_finding(
@@ -386,27 +417,24 @@ def check_vmem_model(root: str, *,
                     f"gauge_headroom_bytes(Y={Y}, Xh={Xh}, "
                     f"itemsize={itemsize}, gauge_comps={gc}) = "
                     f"{got_head}, independent estimate {want_head} "
-                    f"((18-{gc}) planes x 12 plane-sets x 2 buffers)"))
-            lim_gc = limit + want_head
-            row = itemsize * 4 * 24 * Y * Xh       # one (Z=4) t-row
-            T_gc = lim_gc // row
+                    f"((18-{gc}) tiled planes x 12 plane-sets x 2 "
+                    "buffers)"))
+            row = 4 * 24 * tiled_plane(Y, Xh, itemsize)   # one (Z=4) row
+            T_gc = budget("resident", (1, 4, 24, Y, Xh), itemsize,
+                          gc) // row
             for T in (T_gc, T_gc + 1):
                 shape = (T, 4, 24, Y, Xh)
-                resident = itemsize * math.prod(shape)
+                resident, ringsz, res_ok, want_policy = estimate(
+                    shape, itemsize, gc)
                 got_fits = ws.fused_dhat_fits(shape, dtype,
                                               gauge_comps=gc)
-                if got_fits != (resident <= lim_gc):
+                if got_fits != res_ok:
                     findings.append(_finding(
                         root, "J3",
                         f"fused_dhat_fits({shape}, "
                         f"{jnp.dtype(dtype).name}, gauge_comps={gc}) = "
-                        f"{got_fits}, but resident {resident}B vs "
-                        f"limit+headroom {lim_gc}B says "
-                        f"{resident <= lim_gc}"))
-                ringsz = ring(shape, dtype)
-                want_policy = ("resident" if resident <= lim_gc else
-                               "stream" if ringsz <= lim_gc else
-                               "unfused")
+                        f"{got_fits}, but resident {resident}B against "
+                        f"the budget at {gc} planes says {res_ok}"))
                 got_policy = ws.fused_dhat_policy(shape, dtype,
                                                   gauge_comps=gc)
                 if got_policy != want_policy:
@@ -415,8 +443,8 @@ def check_vmem_model(root: str, *,
                         f"fused_dhat_policy({shape}, "
                         f"{jnp.dtype(dtype).name}, gauge_comps={gc}) = "
                         f"{got_policy!r}, but the byte math (resident "
-                        f"{resident}B, ring {ringsz}B, limit+headroom "
-                        f"{lim_gc}B) says {want_policy!r}"))
+                        f"{resident}B, ring {ringsz}B at {gc} planes) "
+                        f"says {want_policy!r}"))
 
     # The traffic model reports the same scratch numbers it budgets by.
     model = ws.dhat_stream_traffic_model(16, 8, 8, 4, nrhs=2)
@@ -427,11 +455,12 @@ def check_vmem_model(root: str, *,
             f"dhat_stream_traffic_model reports vmem_ring_bytes="
             f"{model['vmem_ring_bytes']} but stream_ring_bytes says "
             f"{mring} for the same (T=16, Z=8, Y=8, Xh=4, nrhs=2)"))
-    if model["vmem_resident_bytes"] != 4 * math.prod((2, 16, 8, 24, 8, 4)):
+    if model["vmem_resident_bytes"] != 2 * 16 * 8 * 24 * tiled_plane(8, 4,
+                                                                     4):
         findings.append(_finding(
             root, "J3",
             "dhat_stream_traffic_model's vmem_resident_bytes disagrees "
-            "with itemsize * prod(shape)"))
+            "with the tiled plane count of the intermediate"))
     return findings
 
 
@@ -521,7 +550,7 @@ def check_overlap_interleave(root: str, *,
     configuration.
     """
     import jax
-    from jax import core as jcore
+    from jax.extend import core as jcore
     from repro import compat
     from repro.distributed import qcd
     from repro.kernels import layout

@@ -1,11 +1,11 @@
 """R1 — version-drifted JAX APIs go through ``repro.compat`` only.
 
-The supported JAX range is 0.4.37 → current; ``shard_map`` (and its
-``check_rep``/``check_vma`` kwarg), ``jax.make_mesh`` /
-``jax.sharding.AxisType``, ``jax.lax.axis_size``, and the Pallas TPU
-compiler-params class all moved between those releases.  Touching any
-of them directly re-introduces the exact breakage PR 1 fixed 25 seed
-tests for.  ``src/repro/compat.py`` is the one place allowed to; the
+``shard_map`` (and its ``check_rep``/``check_vma`` kwarg),
+``jax.make_mesh`` / ``jax.sharding.AxisType``, ``jax.lax.axis_size``,
+the Pallas TPU compiler-params class and the scoped x64 context
+(``jax.experimental.enable_x64``, now ``jax.enable_x64``) all moved
+between JAX releases.  Touching any of them directly re-introduces the
+breakage the compat layer exists to contain.  ``src/repro/compat.py`` is the one place allowed to; the
 Pallas kernels under ``src/repro/kernels/`` may additionally import
 ``jax.experimental.pallas`` (plain ``pallas as pl`` / ``tpu as
 pltpu``) — but even they must take compiler params via
@@ -18,7 +18,8 @@ from typing import Iterable
 
 RULE_ID = "R1"
 DESCRIPTION = ("version-drifted JAX APIs (shard_map, make_mesh/AxisType, "
-               "axis_size, Pallas compiler params) only via repro.compat")
+               "axis_size, Pallas compiler params, enable_x64) only via "
+               "repro.compat")
 
 # The one module allowed to touch everything below.
 COMPAT_PATH = "src/repro/compat.py"
@@ -36,6 +37,8 @@ DRIFTED_PATHS = frozenset({
     "jax.core.axis_frame",
     "jax.experimental.pallas.tpu.TPUCompilerParams",
     "jax.experimental.pallas.tpu.CompilerParams",
+    "jax.experimental.enable_x64",
+    "jax.enable_x64",
 })
 
 # Module prefixes whose *import* is restricted to kernels/ (+ compat).

@@ -213,7 +213,60 @@ class WilsonMatrix:
         m.gauge_audit = self.gauge_audit
         m.fallback_events = self.fallback_events + (
             (self.backend.name, reason),)
+        # Rebinding ran where the exact gauge lives (the host, for a
+        # matrix moved by to_device); the fallback serves from where
+        # this matrix did.
+        here = self.devices()
+        if len(here) == 1 and m.devices() != here:
+            m = m.to_device(next(iter(here)))
         return m
+
+    # --- placement ------------------------------------------------------
+
+    def devices(self) -> set:
+        """The devices the bound gauge leaves live on (empty for a
+        matrix without leaves, or one whose leaves are being traced)."""
+        out = set()
+        for leaf in self._gauge:
+            if (isinstance(leaf, jax.core.Tracer)
+                    or not hasattr(leaf, "sharding")):
+                return set()
+            out |= leaf.sharding.device_set
+        return out
+
+    def to_device(self, device) -> "WilsonMatrix":
+        """This matrix with its gauge leaves moved to ``device``.  The
+        bind-time state (resilience flags, audit, and the exact complex
+        gauge, which stays where it was) carries over."""
+        m = jax.device_put(self, device)
+        m._exact_gauge = self._exact_gauge
+        m.fallback_enabled = self.fallback_enabled
+        m.fallback_events = self.fallback_events
+        m.requested_backend = self.requested_backend
+        m.gauge_audit = self.gauge_audit
+        return m
+
+    def foreign_home(self, psi):
+        """The device ``psi`` is committed to, when that is a device the
+        bound operator is not on (a host-resident source for an
+        accelerator-resident matrix); else ``None``.
+
+        Complex spinors from such a home are encoded there and only the
+        native vectors cross to the operator: see :meth:`encode`."""
+        if (isinstance(psi, jax.core.Tracer)
+                or not getattr(psi, "committed", False)):
+            return None
+        home = psi.sharding.device_set
+        here = self.devices()
+        if len(home) != 1 or not here or home & here:
+            return None
+        return next(iter(home))
+
+    def _place(self, v):
+        here = self.devices()
+        if len(here) == 1 and v.sharding.device_set != here:
+            return jax.device_put(v, next(iter(here)))
+        return v
 
     @classmethod
     def from_ops(cls, ops, kappa: float, gauge=None,
@@ -297,19 +350,26 @@ class WilsonMatrix:
 
     def _complex_op(self, psi, fn, fn_batched):
         ops = self.ops
-        if self._batched(psi):
-            out = ops.from_domain_batched(
-                fn_batched(ops.to_domain_batched(psi), self.kappa))
-        else:
-            out = ops.from_domain(fn(ops.to_domain(psi), self.kappa))
+        home = self.foreign_home(psi)
+        v = self.encode(psi)
+        out = (fn_batched if self._batched(psi) else fn)(v, self.kappa)
+        if home is not None:
+            out = jax.device_put(out, home)
+        out = (ops.from_domain_batched(out) if self._batched(psi)
+               else ops.from_domain(out))
         return out.astype(psi.dtype) if hasattr(psi, "dtype") else out
 
     # native-domain boundary --------------------------------------------
 
     def encode(self, psi):
-        """Complex spinor -> native vector (batched by a leading axis)."""
-        return (self.ops.to_domain_batched(psi) if self._batched(psi)
-                else self.ops.to_domain(psi))
+        """Complex spinor -> native vector (batched by a leading axis).
+
+        A source committed to a device the operator is not on (see
+        :meth:`foreign_home`) is converted there; only the native vector
+        moves to the operator's device."""
+        v = (self.ops.to_domain_batched(psi) if self._batched(psi)
+             else self.ops.to_domain(psi))
+        return v if self.foreign_home(psi) is None else self._place(v)
 
     def decode(self, v, dtype=jnp.complex64):
         """Native vector -> complex spinor."""

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 
 from repro import backends
 from repro.core import solver as _solver
+from repro.kernels.wilson_stencil import resolve_interpret
 
 from .matrix import WilsonMatrix
 from .specs import SolveSpec
@@ -131,17 +132,19 @@ class SolveSession:
             xi_e, xi_o, res = entry.fn(eta_e, eta_o)
         else:
             ops = self.matrix.ops
-            if batched:
-                v_e = ops.to_domain_batched(eta_e)
-                v_o = ops.to_domain_batched(eta_o)
-            else:
-                v_e, v_o = ops.to_domain(eta_e), ops.to_domain(eta_o)
+            # Sources committed to another device than the operator's
+            # (the host, for an accelerator-resident matrix) are encoded
+            # and decoded there; only native vectors cross.
+            home = self.matrix.foreign_home(eta_e)
+            v_e, v_o = self.matrix.encode(eta_e), self.matrix.encode(eta_o)
             if entry.deflation is not None:
                 x, v_xi_o, res = entry.fn(v_e, v_o,
                                           entry.deflation.basis)
             else:
                 x, v_xi_o, res = entry.fn(v_e, v_o)
             x_native = x
+            if home is not None:
+                x, v_xi_o = jax.device_put((x, v_xi_o), home)
             from_dom = (ops.from_domain_batched if batched
                         else ops.from_domain)
             # Decode keeps the caller's spinor dtype (c128 under x64).
@@ -236,14 +239,17 @@ class SolveSession:
         """A ``bops_factory`` for the refined solve's precision ladder:
         rebinds the *session's* backend at the requested rung when its
         capabilities allow, else drops to the jnp reference operator at
-        the matching complex dtype (always available)."""
+        the matching complex dtype (always available) — so the f64 rung of
+        a compiled Pallas backend, which has no f64 kernel, runs on jnp."""
         matrix = self.matrix
 
         def factory(rung: str):
             bspec = matrix.backend
             caps = backends.backend_info(bspec.name)
             U_e, U_o = matrix.gauge_complex(jnp.complex128)
-            if rung in caps.dtypes:
+            compiled_only = (rung in caps.interpret_only_dtypes
+                             and not resolve_interpret(bspec.interpret))
+            if rung in caps.dtypes and not compiled_only:
                 spec2 = dataclasses.replace(bspec, dtype=rung)
                 extra = (matrix._opaque.value
                          if (matrix._rebuild == "native"

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from repro import backends
 from repro.core import solver as _solver
+from repro.kernels.wilson_stencil import resolve_interpret
 
 __all__ = ["LatticeSpec", "BackendSpec", "SolveSpec"]
 
@@ -188,6 +189,13 @@ class BackendSpec:
             raise ValueError(
                 f"backend {name!r} has no interpret mode; drop "
                 f"BackendSpec.interpret [capabilities: {caps}]")
+        if (self.dtype in caps.interpret_only_dtypes
+                and not resolve_interpret(self.interpret)):
+            raise ValueError(
+                f"backend {name!r} has no compiled {self.dtype} kernel "
+                f"for the TPU; {self.dtype} runs only in the Pallas "
+                f"interpreter (interpret=True), or choose the jnp "
+                f"backend [capabilities: {caps}]")
         if (self.gauge_compression != "none"
                 and self.gauge_compression not in caps.gauge_compressions):
             raise ValueError(

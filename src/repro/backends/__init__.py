@@ -245,6 +245,9 @@ class BackendCapabilities:
       gauge dtype, like ``"jnp"``).
     * ``supports_interpret`` — whether the factory takes ``interpret=``
       (Pallas interpreter off-TPU).
+    * ``interpret_only_dtypes`` — dtypes among ``dtypes`` that only the
+      interpreter runs: the TPU compiler builds no kernel for them
+      (f64 on the Pallas backends).
     * ``policies`` — the fused-Dhat execution paths the backend can take
       per application (policy introspection; ``"auto"`` means it picks
       among the others by VMEM footprint).
@@ -266,6 +269,7 @@ class BackendCapabilities:
     batched_kernels: bool = False
     dtypes: tuple = ()
     supports_interpret: bool = False
+    interpret_only_dtypes: tuple = ()
     policies: tuple = ()
     gauge_compressions: tuple = ("none",)
     fallback: "str | None" = None

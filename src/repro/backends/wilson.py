@@ -31,6 +31,7 @@ import jax.numpy as jnp
 
 from repro import compat
 from repro.core import evenodd, gamma
+from repro.core.lattice import EXACT
 from repro.kernels import layout, ops
 
 from . import BackendCapabilities, WilsonOps, register_backend
@@ -41,8 +42,9 @@ def _dagger_via_gamma5(apply_dhat):
     g5 = jnp.asarray(gamma.GAMMA5)
 
     def fn(psi_e, kappa):
-        gp = jnp.einsum("ij,...jc->...ic", g5, psi_e)
-        return jnp.einsum("ij,...jc->...ic", g5, apply_dhat(gp, kappa))
+        gp = jnp.einsum("ij,...jc->...ic", g5, psi_e, precision=EXACT)
+        return jnp.einsum("ij,...jc->...ic", g5, apply_dhat(gp, kappa),
+                          precision=EXACT)
 
     return fn
 
@@ -391,7 +393,8 @@ register_backend(
     capabilities=BackendCapabilities(
         name="pallas", domain="planar", gauge_form="planar",
         batched_kernels=True, dtypes=_PALLAS_DTYPES,
-        supports_interpret=True, policies=("unfused",),
+        supports_interpret=True, interpret_only_dtypes=("f64",),
+        policies=("unfused",),
         gauge_compressions=_GAUGE_COMPRESSIONS, fallback="jnp",
         description="planar Pallas stencil, one kernel per hopping "
                     "block (two kernels per Dhat)"),
@@ -402,7 +405,7 @@ register_backend(
     capabilities=BackendCapabilities(
         name="pallas_fused", domain="planar", gauge_form="planar",
         batched_kernels=True, dtypes=_PALLAS_DTYPES,
-        supports_interpret=True,
+        supports_interpret=True, interpret_only_dtypes=("f64",),
         policies=("auto", "resident", "stream", "unfused"),
         gauge_compressions=_GAUGE_COMPRESSIONS, fallback="pallas",
         description="Dhat as ONE kernel; three-way auto policy sized by "
@@ -415,7 +418,8 @@ register_backend(
     capabilities=BackendCapabilities(
         name="pallas_fused_stream", domain="planar", gauge_form="planar",
         batched_kernels=True, dtypes=_PALLAS_DTYPES,
-        supports_interpret=True, policies=("stream",),
+        supports_interpret=True, interpret_only_dtypes=("f64",),
+        policies=("stream",),
         gauge_compressions=_GAUGE_COMPRESSIONS,
         fallback="pallas_fused",
         description="streaming plane-window fused Dhat, forced: VMEM "

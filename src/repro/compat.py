@@ -1,115 +1,72 @@
-"""Version-adaptive shims over the JAX APIs that drifted between releases.
+"""The JAX APIs that drifted between releases, behind one module.
 
-The repo targets JAX 0.4.37 (the pinned CI version) through current
-releases.  Three API families moved underneath us:
+The repo runs on the installed JAX (0.9).  Four API families moved in
+recent releases, and every caller in the repo reaches them through here
+so that the next move is fixed in one place:
 
-* ``shard_map``: ``jax.experimental.shard_map.shard_map(..., check_rep=)``
-  on 0.4.x became ``jax.shard_map(..., check_vma=)`` on newer releases.
-* Pallas TPU compiler params: ``pltpu.TPUCompilerParams`` was renamed to
-  ``pltpu.CompilerParams``.
-* ``jax.make_mesh``: newer releases grew an ``axis_types=`` kwarg and the
-  ``jax.sharding.AxisType`` enum; 0.4.37 has neither (every mesh axis is
-  implicitly "auto").
+* ``jax.shard_map(..., check_vma=)`` (was ``jax.experimental.shard_map``
+  with ``check_rep=``);
+* the Pallas TPU compiler params, ``pltpu.CompilerParams`` (was
+  ``pltpu.TPUCompilerParams``);
+* ``jax.make_mesh(..., axis_types=)`` with ``jax.sharding.AxisType``;
+* the scoped x64 context, ``jax.enable_x64`` (was
+  ``jax.experimental.enable_x64``).
 
-Everything in the repo that needs one of these goes through this module;
-nothing else may touch the moved names directly.
+Lint rule R1 keeps everything else off the moved names.
 """
 from __future__ import annotations
 
-import inspect
+import dataclasses
 from typing import Optional, Sequence
 
 import jax
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["shard_map", "tpu_compiler_params", "make_mesh", "axis_size",
-           "HAS_AXIS_TYPE", "JAX_VERSION"]
-
-JAX_VERSION = jax.__version__
-
-# --- shard_map -------------------------------------------------------------
-
-if hasattr(jax, "shard_map"):                     # JAX >= 0.6-ish
-    _shard_map_impl = jax.shard_map
-else:                                             # 0.4.x fallback
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-_SHARD_MAP_PARAMS = frozenset(
-    inspect.signature(_shard_map_impl).parameters)
+           "enable_x64"]
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``shard_map`` with the new-style keyword interface on every version.
-
-    ``check_vma`` is the current name of 0.4.x's ``check_rep``; we accept
-    the new name and translate down when running on an old JAX.
-    """
-    kwargs = {"mesh": mesh, "in_specs": in_specs, "out_specs": out_specs}
-    if "check_vma" in _SHARD_MAP_PARAMS:
-        kwargs["check_vma"] = check_vma
-    elif "check_rep" in _SHARD_MAP_PARAMS:
-        kwargs["check_rep"] = check_vma
-    return _shard_map_impl(f, **kwargs)
+    """``jax.shard_map`` with keyword-only specs."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
-# --- static mesh-axis size inside shard_map ---------------------------------
-
-if hasattr(jax.lax, "axis_size"):
-
-    def axis_size(name) -> int:
-        """Static size of a named mesh axis, usable inside ``shard_map``."""
-        return jax.lax.axis_size(name)
-
-else:  # 0.4.x: the axis frame carries the size directly
-
-    def axis_size(name) -> int:
-        """Static size of a named mesh axis, usable inside ``shard_map``."""
-        return jax.core.axis_frame(name)
+def axis_size(name) -> int:
+    """Static size of a named mesh axis, usable inside ``shard_map``."""
+    return jax.lax.axis_size(name)
 
 
-# --- Pallas TPU compiler params --------------------------------------------
-
-_COMPILER_PARAMS_CLS = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
+_COMPILER_PARAMS_FIELDS = frozenset(
+    f.name for f in dataclasses.fields(pltpu.CompilerParams))
 
 
 def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` / ``pltpu.TPUCompilerParams``, whichever
-    this JAX provides.  Unknown fields are dropped (newer JAX occasionally
-    renames them) rather than crashing an old pin."""
-    valid = frozenset(
-        inspect.signature(_COMPILER_PARAMS_CLS.__init__).parameters)
-    return _COMPILER_PARAMS_CLS(
-        **{k: v for k, v in kwargs.items() if k in valid})
-
-
-# --- mesh construction ------------------------------------------------------
-
-HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-_MAKE_MESH_PARAMS = frozenset(
-    inspect.signature(jax.make_mesh).parameters)
+    """``pltpu.CompilerParams``; an unknown field raises, so a renamed
+    ``dimension_semantics`` or ``vmem_limit_bytes`` cannot be dropped
+    without notice."""
+    unknown = sorted(set(kwargs) - _COMPILER_PARAMS_FIELDS)
+    if unknown:
+        raise TypeError(
+            f"pltpu.CompilerParams of JAX {jax.__version__} has no "
+            f"field(s) {unknown}; known: {sorted(_COMPILER_PARAMS_FIELDS)}")
+    return pltpu.CompilerParams(**kwargs)
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               devices: Optional[Sequence] = None,
               explicit: bool = False):
-    """``jax.make_mesh`` with auto-typed axes on every JAX version.
+    """``jax.make_mesh`` with every axis typed ``Auto`` (or ``Explicit``
+    when ``explicit=True``), so the behaviour does not follow whatever
+    the default axis type drifts to."""
+    ty = (jax.sharding.AxisType.Explicit if explicit
+          else jax.sharding.AxisType.Auto)
+    kwargs = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(ty,) * len(tuple(axis_names)),
+                         **kwargs)
 
-    On 0.4.37 there is no ``AxisType`` and every axis is auto, so the
-    kwarg is simply omitted.  On newer JAX we pass ``AxisType.Auto``
-    explicitly (or ``AxisType.Explicit`` when ``explicit=True``) so the
-    behaviour matches the old default instead of whatever the new default
-    drifts to.
-    """
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if HAS_AXIS_TYPE and "axis_types" in _MAKE_MESH_PARAMS:
-        ty = (jax.sharding.AxisType.Explicit if explicit
-              else jax.sharding.AxisType.Auto)
-        kwargs["axis_types"] = (ty,) * len(tuple(axis_names))
-    elif explicit:
-        raise NotImplementedError(
-            f"explicit mesh axes need jax.sharding.AxisType "
-            f"(JAX {JAX_VERSION} predates it)")
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+
+def enable_x64(new_val: bool = True):
+    """Context manager that turns 64-bit dtypes on (or off) inside it."""
+    return jax.enable_x64(new_val)

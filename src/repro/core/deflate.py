@@ -54,6 +54,7 @@ import jax.numpy as jnp
 import numpy as _np
 
 from . import solver as _sol
+from .lattice import EXACT
 
 
 class DeflationBasis(NamedTuple):
@@ -106,9 +107,10 @@ def _stack_dot(vecs, v, batched: bool):
         w, x = _sol._acc(w), _sol._acc(x)
         wf = jnp.conj(w).reshape(w.shape[0], -1)
         if batched:
-            c = wf @ x.reshape(x.shape[0], -1).T
+            c = jnp.matmul(wf, x.reshape(x.shape[0], -1).T,
+                           precision=EXACT)
         else:
-            c = wf @ x.reshape(-1)
+            c = jnp.matmul(wf, x.reshape(-1), precision=EXACT)
         out = c if out is None else out + c
     return out
 
@@ -120,7 +122,7 @@ def _stack_comb(coef, vecs):
     leaf dtype (see ``solver._apply_scalar``)."""
     def leaf(w):
         c = _sol._apply_scalar(coef, w)
-        return jnp.tensordot(c, w, axes=((0,), (0,)))
+        return jnp.tensordot(c, w, axes=((0,), (0,)), precision=EXACT)
     return jax.tree_util.tree_map(leaf, vecs)
 
 
@@ -129,7 +131,7 @@ def _mix(coef, stacked):
     ``out_i = sum_j coef[i, j] * V_j``."""
     def leaf(v):
         c = _sol._apply_scalar(coef, v)
-        return jnp.tensordot(c, v, axes=((1,), (0,)))
+        return jnp.tensordot(c, v, axes=((1,), (0,)), precision=EXACT)
     return jax.tree_util.tree_map(leaf, stacked)
 
 
@@ -349,7 +351,8 @@ def make_ritz_refine(quality: float = RITZ_QUALITY):
         r2 = _sol._bnorm2(resid)
         # weight of each eigenvector on EMPTY slots: exactly 1 for the
         # sentinel block's pairs, exactly 0 for genuine ones.
-        wempty = ((1.0 - mf.real)[None, :] @ (jnp.abs(y) ** 2)).ravel()
+        wempty = jnp.matmul((1.0 - mf.real)[None, :], jnp.abs(y) ** 2,
+                            precision=EXACT).ravel()
         accept = jnp.logical_and(
             jnp.logical_and(wempty < 0.5, theta > 0.0),
             r2 <= (quality * theta) ** 2)

@@ -23,7 +23,8 @@ from typing import Tuple
 import jax.numpy as jnp
 
 from . import gamma, wilson
-from .lattice import AXIS_OF_MU, MU_X, MU_Y, MU_Z, NDIM, row_parity
+from .lattice import (AXIS_OF_MU, EXACT, MU_X, MU_Y, MU_Z, NDIM,
+                      row_parity)
 
 EVEN, ODD = 0, 1
 
@@ -98,13 +99,13 @@ def hop_block(U_e: jnp.ndarray, U_o: jnp.ndarray, src: jnp.ndarray,
         # Forward: (1 - g_mu) U_mu(x) src(x + mu).
         fwd = eo_shift(src, mu, +1, out_parity, parity_offset)
         h = gamma.project(fwd, mu, s=-1)
-        uh = jnp.einsum("...ab,...hb->...ha", U_out[mu], h)
+        uh = jnp.einsum("...ab,...hb->...ha", U_out[mu], h, precision=EXACT)
         out = out + gamma.reconstruct(uh, mu, s=-1)
         # Backward: (1 + g_mu) U_mu^dag(x - mu) src(x - mu).
         bwd = eo_shift(src, mu, -1, out_parity, parity_offset)
         u_bwd = eo_shift(U_in[mu], mu, -1, out_parity, parity_offset)
         h = gamma.project(bwd, mu, s=+1)
-        uh = jnp.einsum("...ba,...hb->...ha", u_bwd.conj(), h)
+        uh = jnp.einsum("...ba,...hb->...ha", u_bwd.conj(), h, precision=EXACT)
         out = out + gamma.reconstruct(uh, mu, s=+1)
     return out
 
@@ -133,9 +134,9 @@ def apply_dhat(U_e, U_o, psi_e, kappa, hop_oe_fn=None, hop_eo_fn=None):
 def apply_dhat_dagger(U_e, U_o, psi_e, kappa, hop_oe_fn=None, hop_eo_fn=None):
     """``Dhat^dag`` via gamma5-hermiticity (g5 Dhat g5 = Dhat^dag)."""
     g5 = jnp.asarray(gamma.GAMMA5)
-    g5psi = jnp.einsum("ij,...jc->...ic", g5, psi_e)
+    g5psi = jnp.einsum("ij,...jc->...ic", g5, psi_e, precision=EXACT)
     out = apply_dhat(U_e, U_o, g5psi, kappa, hop_oe_fn, hop_eo_fn)
-    return jnp.einsum("ij,...jc->...ic", g5, out)
+    return jnp.einsum("ij,...jc->...ic", g5, out, precision=EXACT)
 
 
 def apply_wilson_eo(U_e, U_o, psi_e, psi_o, kappa):
@@ -195,10 +196,10 @@ def hop_block_ext(U_out: jnp.ndarray, U_in_ext: jnp.ndarray,
     for mu in range(NDIM):
         fwd, bwd, u_bwd = fwd_bwd(mu)
         h = gamma.project(fwd, mu, s=-1)
-        uh = jnp.einsum("...ab,...hb->...ha", U_out[mu], h)
+        uh = jnp.einsum("...ab,...hb->...ha", U_out[mu], h, precision=EXACT)
         out = out + gamma.reconstruct(uh, mu, s=-1)
         h = gamma.project(bwd, mu, s=+1)
-        uh = jnp.einsum("...ba,...hb->...ha", u_bwd.conj(), h)
+        uh = jnp.einsum("...ba,...hb->...ha", u_bwd.conj(), h, precision=EXACT)
         out = out + gamma.reconstruct(uh, mu, s=+1)
     return out
 

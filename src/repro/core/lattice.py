@@ -18,8 +18,15 @@ from __future__ import annotations
 import dataclasses
 from typing import Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+
+# Precision of every matrix product in the lattice numerics.  The TPU's
+# default rounds f32 matmul operands to bf16 (about three significant
+# digits); the operators, the residual checks and the Krylov reductions
+# need exact f32 products there, as they get on the CPU.
+EXACT = jax.lax.Precision.HIGHEST
 
 # Direction indices.
 MU_X, MU_Y, MU_Z, MU_T = 0, 1, 2, 3

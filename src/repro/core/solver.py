@@ -58,6 +58,8 @@ from typing import Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from .lattice import EXACT
+
 
 # Krylov scalars (<a,b>, |r|^2, alpha/beta/rho/omega) of sub-f32 vector
 # domains (bf16 planar vectors of the mixed-precision inner solve)
@@ -95,7 +97,8 @@ def _apply_scalar(alpha, leaf):
 
 def _vdot(a, b):
     leaves_a, leaves_b = jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)
-    return sum(jnp.vdot(_acc(x), _acc(y)) for x, y in zip(leaves_a, leaves_b))
+    return sum(jnp.vdot(_acc(x), _acc(y), precision=EXACT)
+               for x, y in zip(leaves_a, leaves_b))
 
 
 def _axpy(alpha, x, y):
@@ -151,8 +154,8 @@ def _bgram(a, b):
     out = None
     for x, y in zip(leaves_a, leaves_b):
         x, y = _acc(x), _acc(y)
-        g = jnp.conj(x).reshape(x.shape[0], -1) @ \
-            y.reshape(y.shape[0], -1).T
+        g = jnp.matmul(jnp.conj(x).reshape(x.shape[0], -1),
+                       y.reshape(y.shape[0], -1).T, precision=EXACT)
         out = g if out is None else out + g
     return out
 
@@ -164,7 +167,7 @@ def _bcomb(coef, x, y=None):
     the batch)."""
     def leaf(xi, yi=None):
         c = _apply_scalar(coef, xi)
-        upd = jnp.tensordot(c, xi, axes=((0,), (0,)))
+        upd = jnp.tensordot(c, xi, axes=((0,), (0,)), precision=EXACT)
         return upd if yi is None else upd + yi
     if y is None:
         return jax.tree_util.tree_map(leaf, x)
@@ -990,7 +993,8 @@ def blockcg_batched(op: Callable, b, x0=None, *, tol: float = 1e-6,
         inv_cl = jnp.linalg.inv(jnp.conj(low))
         q = jax.tree_util.tree_map(
             lambda leaf: jnp.tensordot(_apply_scalar(inv_cl, leaf),
-                                       leaf, axes=((1,), (0,))), rt)
+                                       leaf, axes=((1,), (0,)),
+                                       precision=EXACT), rt)
         return q, jnp.conj(low).T
 
     def _snorm2(s):
@@ -1030,11 +1034,11 @@ def blockcg_batched(op: Callable, b, x0=None, *, tol: float = 1e-6,
             # Deflated directions break the BCGrQ identity P^H Q = I
             # the plain step relies on; the exact small step is
             # M = (P^H A P)^{-1} (P^H Q) — one extra nrhs x nrhs Gram.
-            alpha = alpha @ _bgram(p, qm)
-        x1 = _bcomb(alpha @ s, p, x)
+            alpha = jnp.matmul(alpha, _bgram(p, qm), precision=EXACT)
+        x1 = _bcomb(jnp.matmul(alpha, s, precision=EXACT), p, x)
         t = _bcomb(-alpha, ap, qm)
         qm1, c1 = _chol_qr(t)
-        s1 = c1 @ s
+        s1 = jnp.matmul(c1, s, precision=EXACT)
         p1 = _bcomb(jnp.conj(c1).T, p, proj(qm1))
         rr1 = _snorm2(s1)
         recomp = ((k + 1) % recompute_every == 0) if recompute_every \
@@ -1336,7 +1340,7 @@ def make_refined_solve(bops, U64_e, U64_o, kappa, *, method: str = "cgnr",
         raise ValueError(
             "mixed-precision refinement needs float64 for the outer "
             "residual: enable x64 (jax.config.update('jax_enable_x64', "
-            "True) or the jax.experimental.enable_x64 context)")
+            "True) or the repro.compat.enable_x64 context)")
 
     U64_e = U64_e.astype(jnp.complex128)
     U64_o = U64_o.astype(jnp.complex128)

@@ -6,7 +6,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
-from .lattice import NDIM, shift
+from .lattice import EXACT, NDIM, shift
 
 
 def random_su3(key: jax.Array, shape: Sequence[int], dtype=jnp.complex64) -> jnp.ndarray:
@@ -122,7 +122,7 @@ def reconstruct_minimal(W: jnp.ndarray, dtype=jnp.complex64) -> jnp.ndarray:
 def unitarity_defect(U: jnp.ndarray) -> jnp.ndarray:
     """max |U U^dag - 1| over the field; ~1e-6 for healthy f32 SU(3)."""
     eye = jnp.eye(3, dtype=U.dtype)
-    uud = jnp.einsum("...ab,...cb->...ac", U, U.conj())
+    uud = jnp.einsum("...ab,...cb->...ac", U, U.conj(), precision=EXACT)
     return jnp.max(jnp.abs(uud - eye))
 
 
@@ -136,7 +136,7 @@ def project_su3(U: jnp.ndarray) -> jnp.ndarray:
     non-singular; replace corrupted links first.
     """
     w, _, vh = jnp.linalg.svd(U)
-    q = jnp.einsum("...ab,...bc->...ac", w, vh)
+    q = jnp.einsum("...ab,...bc->...ac", w, vh, precision=EXACT)
     det = jnp.linalg.det(q)
     return q * (det[..., None, None] ** (-1.0 / 3.0))
 
@@ -155,7 +155,8 @@ def plaquette(U: jnp.ndarray) -> jnp.ndarray:
             u_nu_xmu = shift(u_nu, mu, +1)  # U_nu(x+mu)
             u_mu_xnu = shift(u_mu, nu, +1)  # U_mu(x+nu)
             p = jnp.einsum("...ab,...bc,...dc,...ed->...ae",
-                           u_mu, u_nu_xmu, u_mu_xnu.conj(), u_nu.conj())
+                           u_mu, u_nu_xmu, u_mu_xnu.conj(), u_nu.conj(),
+                           precision=EXACT)
             tr = jnp.trace(p, axis1=-2, axis2=-1)
             total = total + jnp.mean(tr.real)
             count += 1

@@ -14,7 +14,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from . import gamma
-from .lattice import NDIM, shift
+from .lattice import EXACT, NDIM, shift
 
 # Flop count per site of one hopping application, QXS convention (paper
 # Sec. 2): 8 hops x (project 12 + SU(3) x half-spinor 132 + reconstruct 12)
@@ -33,13 +33,13 @@ def hop(U: jnp.ndarray, psi: jnp.ndarray) -> jnp.ndarray:
         # Forward: (1 - g_mu) U_mu(x) psi(x + mu).
         fwd = shift(psi, mu, +1)
         h = gamma.project(fwd, mu, s=-1)
-        uh = jnp.einsum("...ab,...hb->...ha", U[mu], h)
+        uh = jnp.einsum("...ab,...hb->...ha", U[mu], h, precision=EXACT)
         out = out + gamma.reconstruct(uh, mu, s=-1)
         # Backward: (1 + g_mu) U_mu^dag(x - mu) psi(x - mu).
         bwd = shift(psi, mu, -1)
         u_bwd = shift(U[mu], mu, -1)  # U_mu(x - mu)
         h = gamma.project(bwd, mu, s=+1)
-        uh = jnp.einsum("...ba,...hb->...ha", u_bwd.conj(), h)
+        uh = jnp.einsum("...ba,...hb->...ha", u_bwd.conj(), h, precision=EXACT)
         out = out + gamma.reconstruct(uh, mu, s=+1)
     return out
 
@@ -52,5 +52,6 @@ def apply_wilson(U: jnp.ndarray, psi: jnp.ndarray, kappa: float) -> jnp.ndarray:
 def apply_wilson_dagger(U: jnp.ndarray, psi: jnp.ndarray, kappa: float) -> jnp.ndarray:
     """``D_W^dag psi`` via gamma5-hermiticity: ``D^dag = g5 D g5``."""
     g5 = jnp.asarray(gamma.GAMMA5)
-    g5psi = jnp.einsum("ij,...jc->...ic", g5, psi)
-    return jnp.einsum("ij,...jc->...ic", g5, apply_wilson(U, g5psi, kappa))
+    g5psi = jnp.einsum("ij,...jc->...ic", g5, psi, precision=EXACT)
+    return jnp.einsum("ij,...jc->...ic", g5, apply_wilson(U, g5psi, kappa),
+                      precision=EXACT)

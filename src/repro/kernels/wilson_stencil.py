@@ -207,6 +207,32 @@ def _hop_plane(p, pzp, pzm, ptp, ptm, u_out, ux, uy, uz, ut,
     return acc
 
 
+def _origin_parity(tz_offset) -> jnp.ndarray:
+    """``(t0 + z0) % 2`` of the shard origin as the kernels' SMEM operand.
+
+    Each kernel adds its grid ``(t, z)`` to this scalar to get the row
+    parity of the plane it works on.  The operand is a ``(1,)`` array held
+    whole in SMEM (:func:`_origin_parity_spec`): a per-step ``(1, 1)``
+    block of a ``(T, Z)`` parity table is refused by the TPU lowering,
+    whose blocks must be (8, 128)-aligned or span the array.
+    ``tz_offset`` may be traced (the distributed shard origin).
+    """
+    t0, z0 = tz_offset
+    return jnp.reshape(jnp.asarray((t0 + z0) % 2, jnp.int32), (1,))
+
+
+def _origin_parity_spec() -> pl.BlockSpec:
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """``interpret=None`` means: the Pallas interpreter off the TPU, the
+    compiled kernel on it."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)
+
+
 def _plane(ref, batched: bool):
     """Component-leading view of one pipelined spinor block.
 
@@ -230,11 +256,12 @@ def _hop_kernel(*refs, out_parity: int, axpy_coeff: Optional[float],
 
     p = _plane(pc, batched)           # (24, [nrhs,] Y, Xh)
     compute_dtype = p.dtype
+    tz_par = (pl.program_id(0) + pl.program_id(1) + par_ref[0]) % 2
     acc = _hop_plane(p, _plane(pzp, batched), _plane(pzm, batched),
                      _plane(ptp, batched), _plane(ptm, batched),
                      uo[:, 0, 0], uix[0, 0, 0], uiy[0, 0, 0],
                      uizm[0, 0, 0], uitm[0, 0, 0],
-                     par_ref[0, 0], out_parity)
+                     tz_par, out_parity)
 
     result = jnp.stack(acc).astype(compute_dtype)
     if axpy_coeff is not None:
@@ -316,29 +343,31 @@ def hop_block_ext_planar_native(u_out_p: jnp.ndarray,
 
 
 def _build_specs(Tl: int, Zl: int, Y: int, Xh: int, halo: bool,
-                 with_axpy: bool, nrhs: Optional[int] = None,
+                 with_axpy: bool, rhs_block: Optional[int] = None,
                  gauge_comps: int = GAUGE_COMPS):
-    """BlockSpecs for (parity, psi x5, U_out, U_in x4[, psi0]).
+    """BlockSpecs for (origin parity, psi x5, U_out, U_in x4[, psi0]).
 
-    With ``nrhs`` the spinor blocks grow a leading RHS axis covered whole
-    by every grid step (block index 0); the gauge blocks are unchanged —
-    per grid step the pipeline fetches each gauge plane exactly once,
-    independent of the batch size.  ``gauge_comps`` sizes the gauge
+    With ``rhs_block`` the spinor blocks grow a leading RHS axis of that
+    many columns, walked by a third, innermost grid axis ``r``.  The
+    gauge index maps ignore ``r``, so consecutive steps of one (t, z)
+    reuse the fetched gauge planes: each gauge plane is loaded once per
+    (t, z) whatever the batch size.  ``gauge_comps`` sizes the gauge
     component-plane axis (18 full / 12 two_row / 8 minimal).
     """
-    if nrhs is None:
+    if rhs_block is None:
         sblk = (1, 1, SPINOR_COMPS, Y, Xh)
     else:
-        sblk = (nrhs, 1, 1, SPINOR_COMPS, Y, Xh)
+        sblk = (rhs_block, 1, 1, SPINOR_COMPS, Y, Xh)
     gblk1 = (1, 1, 1, gauge_comps, Y, Xh)
 
     def s(im):
-        if nrhs is None:
+        if rhs_block is None:
             return pl.BlockSpec(sblk, im)
-        return pl.BlockSpec(sblk, lambda t, z, _im=im: (0, *_im(t, z)))
+        return pl.BlockSpec(sblk,
+                            lambda t, z, r, _im=im: (r, *_im(t, z)))
 
     def g(im):
-        return pl.BlockSpec(gblk1, im)
+        return pl.BlockSpec(gblk1, lambda t, z, *_r, _im=im: _im(t, z))
 
     if halo:
         # Arrays are halo-extended to (T+2, Z+2) in t/z; +1 recenters.
@@ -370,14 +399,95 @@ def _build_specs(Tl: int, Zl: int, Y: int, Xh: int, halo: bool,
             g(lambda t, z: (3, (t - 1) % Tl, z, 0, 0, 0)),
         ]
 
-    par = pl.BlockSpec((1, 1), lambda t, z: (t, z), memory_space=pltpu.SMEM)
     u_out = pl.BlockSpec((4, 1, 1, gauge_comps, Y, Xh),
-                         lambda t, z: (0, t, z, 0, 0, 0))
-    specs = [par] + psi + [u_out] + u_in
+                         lambda t, z, *_r: (0, t, z, 0, 0, 0))
+    specs = [_origin_parity_spec()] + psi + [u_out] + u_in
     if with_axpy:
         specs.append(s(lambda t, z: (t, z, 0, 0, 0)))
     out = s(lambda t, z: (t, z, 0, 0, 0))
     return specs, out
+
+
+# ---------------------------------------------------------------------------
+# VMEM accounting: the bytes the TPU compiler allocates per kernel.
+# ---------------------------------------------------------------------------
+
+# The scoped-VMEM limit of one kernel on v5e (what the compiler's refusal
+# quotes as "limit 16.00M").  Every kernel and policy below is held to it.
+_VMEM_LIMIT_BYTES = 16 << 20
+
+# Mosaic lays the minor (Y, Xh) dims of every VMEM buffer out in
+# (sublane, lane) tiles: Xh rounds up to 128 lanes and Y to 8 sublanes of
+# 32-bit words (16 rows for bf16).  A (16, 8) f32 plane thus takes
+# 16 x 128 x 4 B = 8 KiB, not 512 B.
+_LANES = 128
+
+# VMEM the unrolled SU(3) x half-spinor body spills, in 32-bit planes per
+# RHS column (the VPU computes bf16 in 32-bit registers).  Compiles for
+# v5e at 16^4 and 32^3x64 planes, nrhs 1-4, needed up to ~130 such planes
+# per column in f32 and ~170 in bf16 beyond the pipeline buffers; 192
+# keeps a margin, so a kernel the model admits is one the compiler takes.
+_BODY_SPILL_PLANES = 192
+
+# Gauge plane-sets (of ``gauge_comps`` planes each) that one grid step
+# keeps in flight, per kernel: the hop block's u_out (4 directions) and
+# four u_in views; the resident fused kernel's u_out + z/t views for each
+# parity; the streaming kernel's two u_out/u_x,y quartets and four
+# shifted views.
+GAUGE_PLANE_SETS = {"hop": 8, "hop_axpy": 8, "resident": 12, "stream": 20}
+
+# Pipelined spinor blocks per grid step (inputs + output), per kernel.
+_SPINOR_BLOCKS = {"hop": 6, "hop_axpy": 7, "resident": 6, "stream": 7}
+
+
+def _itemsize(dtype) -> int:
+    return dtype if isinstance(dtype, int) else jnp.dtype(dtype).itemsize
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def plane_vmem_bytes(Y: int, Xh: int, dtype=jnp.float32) -> int:
+    """VMEM bytes of one ``(Y, Xh)`` plane as the TPU compiler tiles it.
+
+    ``dtype`` may also be an int itemsize.
+    """
+    itemsize = _itemsize(dtype)
+    sublanes = 8 * max(1, 4 // itemsize)
+    return itemsize * _round_up(Y, sublanes) * _round_up(Xh, _LANES)
+
+
+def _pipeline_vmem_bytes(kernel: str, nrhs: int, Y: int, Xh: int, dtype,
+                         gauge_comps: int) -> int:
+    """Double-buffered pipeline blocks plus body spills of one kernel."""
+    planes = (_SPINOR_BLOCKS[kernel] * nrhs * SPINOR_COMPS
+              + GAUGE_PLANE_SETS[kernel] * gauge_comps)
+    spill = plane_vmem_bytes(Y, Xh, max(_itemsize(dtype), 4))
+    return (2 * planes * plane_vmem_bytes(Y, Xh, dtype)
+            + _BODY_SPILL_PLANES * nrhs * spill)
+
+
+def hop_vmem_bytes(rhs_block: int, Y: int, Xh: int, dtype=jnp.float32, *,
+                   gauge_comps: int = GAUGE_COMPS,
+                   with_axpy: bool = False) -> int:
+    """VMEM of one hopping-block kernel over ``rhs_block`` RHS columns."""
+    return _pipeline_vmem_bytes("hop_axpy" if with_axpy else "hop",
+                                rhs_block, Y, Xh, dtype, gauge_comps)
+
+
+def hop_rhs_block(nrhs: int, Y: int, Xh: int, dtype=jnp.float32, *,
+                  gauge_comps: int = GAUGE_COMPS,
+                  with_axpy: bool = False) -> int:
+    """RHS columns per hopping-block grid step: the largest divisor of
+    ``nrhs`` whose :func:`hop_vmem_bytes` fits the VMEM limit (1 if none
+    does — the kernel then refuses the shape)."""
+    for b in range(nrhs, 0, -1):
+        if nrhs % b == 0 and hop_vmem_bytes(
+                b, Y, Xh, dtype, gauge_comps=gauge_comps,
+                with_axpy=with_axpy) <= _VMEM_LIMIT_BYTES:
+            return b
+    return 1
 
 
 def hop_traffic_model(Tl: int, Zl: int, Y: int, Xh: int, *,
@@ -441,9 +551,12 @@ def hop_block_planar(u_out_p: jnp.ndarray, u_in_p: jnp.ndarray,
       axpy: optional ``(coeff, psi0_p)`` fusing ``psi0 + coeff * hop``
         (``psi0_p`` batched iff ``src_p`` is).
       interpret: force/disable interpret mode (default: auto off-TPU).
+
+    A batched source runs in RHS blocks of :func:`hop_rhs_block` columns
+    (the largest divisor of nrhs whose blocks fit VMEM), each gauge plane
+    still fetched once per (t, z).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     batched = src_p.ndim == 6
     nrhs = src_p.shape[0] if batched else None
     lead = 1 if batched else 0
@@ -451,15 +564,26 @@ def hop_block_planar(u_out_p: jnp.ndarray, u_in_p: jnp.ndarray,
     if halo:
         Tl, Zl = Tl - 2, Zl - 2
     Y, Xh = src_p.shape[-2], src_p.shape[-1]
-    t0, z0 = tz_offset
-
-    par = ((jnp.arange(Tl, dtype=jnp.int32)[:, None] + t0)
-           + (jnp.arange(Zl, dtype=jnp.int32)[None, :] + z0)) % 2
 
     with_axpy = axpy is not None
     gauge_comps = u_out_p.shape[3]
+    rhs_block = None
+    grid = (Tl, Zl)
+    if batched:
+        rhs_block = hop_rhs_block(nrhs, Y, Xh, src_p.dtype,
+                                  gauge_comps=gauge_comps,
+                                  with_axpy=with_axpy)
+        grid = (Tl, Zl, nrhs // rhs_block)
+    if not interpret and hop_vmem_bytes(
+            rhs_block or 1, Y, Xh, src_p.dtype, gauge_comps=gauge_comps,
+            with_axpy=with_axpy) > _VMEM_LIMIT_BYTES:
+        raise ValueError(
+            f"hopping block on ({Y}, {Xh}) planes needs more than the "
+            f"{_VMEM_LIMIT_BYTES} B of VMEM a kernel may use, even one "
+            "right-hand side at a time")
     in_specs, out_spec = _build_specs(Tl, Zl, Y, Xh, halo, with_axpy,
-                                      nrhs=nrhs, gauge_comps=gauge_comps)
+                                      rhs_block=rhs_block,
+                                      gauge_comps=gauge_comps)
     coeff = float(axpy[0]) if with_axpy else None
 
     model = hop_traffic_model(Tl, Zl, Y, Xh, nrhs=nrhs or 1,
@@ -472,7 +596,7 @@ def hop_block_planar(u_out_p: jnp.ndarray, u_in_p: jnp.ndarray,
 
     kernel = functools.partial(_hop_kernel, out_parity=out_parity,
                                axpy_coeff=coeff, batched=batched)
-    operands = [par, src_p, src_p, src_p, src_p, src_p,
+    operands = [_origin_parity(tz_offset), src_p, src_p, src_p, src_p, src_p,
                 u_out_p, u_in_p, u_in_p, u_in_p, u_in_p]
     if with_axpy:
         operands.append(axpy[1])
@@ -481,13 +605,13 @@ def hop_block_planar(u_out_p: jnp.ndarray, u_in_p: jnp.ndarray,
     fn = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct(out_shape, src_p.dtype),
-        grid=(Tl, Zl),
+        grid=grid,
         in_specs=in_specs,
         out_specs=out_spec,
         interpret=interpret,
         cost_estimate=cost,
         compiler_params=compat.tpu_compiler_params(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary",) * len(grid)),
         name=f"wilson_hop_{'oe' if out_parity else 'eo'}",
     )
     return fn(*operands)
@@ -496,11 +620,6 @@ def hop_block_planar(u_out_p: jnp.ndarray, u_in_p: jnp.ndarray,
 # ---------------------------------------------------------------------------
 # Fused even-odd preconditioned operator: Dhat in ONE pallas_call.
 # ---------------------------------------------------------------------------
-
-# Conservative VMEM budget for the resident intermediate (v4/v5 cores have
-# ~16 MiB; leave room for the pipelined operand/output blocks).
-_FUSED_SCRATCH_LIMIT_BYTES = 12 << 20
-
 
 def _dhat_kernel(par_ref, pc, pzp, pzm, ptp, ptm,
                  ue_all, ue_zm, ue_tm, uo_all, uo_zm, uo_tm,
@@ -526,7 +645,7 @@ def _dhat_kernel(par_ref, pc, pzp, pzm, ptp, ptm,
     s = pl.program_id(0)
     t = pl.program_id(1)
     z = pl.program_id(2)
-    tz_par = par_ref[0, 0]
+    tz_par = (t + z + par_ref[0]) % 2
     p = _plane(pc, batched)           # psi_e center plane (24, [N,] Y, Xh)
     compute_dtype = p.dtype
 
@@ -581,27 +700,24 @@ def dhat_planar_fused(u_e_p: jnp.ndarray, u_o_p: jnp.ndarray,
     — :func:`fused_dhat_fits` (itemsize derived from the actual dtype)
     tells you.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     batched = psi_e_p.ndim == 6
     nrhs = psi_e_p.shape[0] if batched else None
     lead = 1 if batched else 0
     Tl, Zl = psi_e_p.shape[lead], psi_e_p.shape[lead + 1]
     Y, Xh = psi_e_p.shape[-2], psi_e_p.shape[-1]
-    t0, z0 = tz_offset
 
     gauge_comps = u_e_p.shape[3]
     if not interpret and not fused_dhat_fits(psi_e_p.shape, psi_e_p.dtype,
                                              gauge_comps=gauge_comps):
-        tmp_bytes = psi_e_p.dtype.itemsize * math.prod(psi_e_p.shape)
+        budget = fused_scratch_budget("resident", psi_e_p.shape,
+                                      psi_e_p.dtype, gauge_comps=gauge_comps)
         raise ValueError(
-            f"fused Dhat intermediate needs {tmp_bytes} B of VMEM scratch "
-            f"(> {_FUSED_SCRATCH_LIMIT_BYTES} budget at gauge_comps="
+            f"fused Dhat intermediate needs "
+            f"{fused_scratch_bytes(psi_e_p.shape, psi_e_p.dtype)} B of "
+            f"VMEM scratch (> {budget} budget at gauge_comps="
             f"{gauge_comps}); use the unfused apply_dhat_planar path for "
             "this local volume / nrhs")
-
-    par = ((jnp.arange(Tl, dtype=jnp.int32)[:, None] + t0)
-           + (jnp.arange(Zl, dtype=jnp.int32)[None, :] + z0)) % 2
 
     if batched:
         sblk = (nrhs, 1, 1, SPINOR_COMPS, Y, Xh)
@@ -647,9 +763,7 @@ def dhat_planar_fused(u_e_p: jnp.ndarray, u_o_p: jnp.ndarray,
                                 z * live(s_), 0, 0, 0)),
         ]
 
-    par_spec = pl.BlockSpec((1, 1), lambda _, t, z: (t, z),
-                            memory_space=pltpu.SMEM)
-    in_specs = ([par_spec] + psi_specs
+    in_specs = ([_origin_parity_spec()] + psi_specs
                 + gauge_specs(lambda s_: 1 - s_)    # u_e shifts: pass 0
                 + gauge_specs(lambda s_: s_))       # u_o shifts: pass 1
     out_spec = s(lambda _, t, z: (t, z, 0, 0, 0))
@@ -683,40 +797,62 @@ def dhat_planar_fused(u_e_p: jnp.ndarray, u_o_p: jnp.ndarray,
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         name="wilson_dhat_fused",
     )
-    return fn(par, psi_e_p, psi_e_p, psi_e_p, psi_e_p, psi_e_p,
+    return fn(_origin_parity(tz_offset),
+              psi_e_p, psi_e_p, psi_e_p, psi_e_p, psi_e_p,
               u_e_p, u_e_p, u_e_p, u_o_p, u_o_p, u_o_p)
 
 
 def gauge_headroom_bytes(Y: int, Xh: int, itemsize: int,
-                         gauge_comps: int = GAUGE_COMPS) -> int:
-    """Extra VMEM freed per pipeline stage by compressed gauge blocks.
+                         gauge_comps: int = GAUGE_COMPS,
+                         plane_sets: int = GAUGE_PLANE_SETS["resident"]
+                         ) -> int:
+    """VMEM freed by compressed gauge blocks in a fused kernel's pipeline.
 
-    The fused kernels keep 12 gauge plane-sets in flight per grid step
-    (u_out x4 + u_in x4 shifted views per parity pass over the two
-    pipelined passes), double-buffered by the pipeline.  Compression
-    shrinks each from 18 to ``gauge_comps`` planes of ``(Y, Xh)``, and
-    the scratch budget can absorb the difference — the resident/stream
-    policy thresholds move accordingly.  Zero at ``gauge_comps == 18``.
+    A fused kernel keeps ``plane_sets`` gauge plane-sets in flight per
+    grid step (:data:`GAUGE_PLANE_SETS`), double-buffered by the
+    pipeline.  Compression shrinks each from 18 to ``gauge_comps``
+    tiled ``(Y, Xh)`` planes, and the scratch budget absorbs the
+    difference.  Zero at ``gauge_comps == 18``.
     """
-    return (GAUGE_COMPS - gauge_comps) * 12 * 2 * Y * Xh * itemsize
+    return ((GAUGE_COMPS - gauge_comps) * plane_sets * 2
+            * plane_vmem_bytes(Y, Xh, itemsize))
+
+
+def fused_scratch_budget(kernel: str, psi_e_p_shape, dtype=jnp.float32, *,
+                         gauge_comps: int = GAUGE_COMPS) -> int:
+    """VMEM left for the scratch of fused ``kernel`` ("resident" or
+    "stream"): the per-kernel limit less its pipeline blocks and body
+    spills at full 18-plane links, plus the :func:`gauge_headroom_bytes`
+    that compressed links free.  Negative when not even the pipeline
+    fits."""
+    nrhs = psi_e_p_shape[0] if len(psi_e_p_shape) == 6 else 1
+    Y, Xh = psi_e_p_shape[-2], psi_e_p_shape[-1]
+    return (_VMEM_LIMIT_BYTES
+            - _pipeline_vmem_bytes(kernel, nrhs, Y, Xh, dtype, GAUGE_COMPS)
+            + gauge_headroom_bytes(Y, Xh, _itemsize(dtype), gauge_comps,
+                                   GAUGE_PLANE_SETS[kernel]))
+
+
+def fused_scratch_bytes(psi_e_p_shape, dtype=jnp.float32) -> int:
+    """VMEM bytes of the resident kernel's scratch: the whole (batched)
+    odd intermediate, ``nrhs * T * Z * 24`` tiled ``(Y, Xh)`` planes."""
+    return (math.prod(psi_e_p_shape[:-2])
+            * plane_vmem_bytes(psi_e_p_shape[-2], psi_e_p_shape[-1], dtype))
 
 
 def fused_dhat_fits(psi_e_p_shape, dtype=jnp.float32, *,
                     gauge_comps: int = GAUGE_COMPS) -> bool:
-    """Whether the fused kernel's VMEM-resident intermediate fits.
+    """Whether the resident fused kernel's VMEM intermediate fits.
 
     ``psi_e_p_shape`` is the (possibly batched) planar spinor shape —
-    ``(T, Z, 24, Y, Xh)`` or ``(nrhs, T, Z, 24, Y, Xh)``; the scratch is
-    exactly that many elements.  ``dtype`` sizes one element (an int
-    itemsize is also accepted for backward compatibility) — f64 under
-    x64 halves the admissible volume versus f32, bf16 doubles it.
-    Compressed links (``gauge_comps`` < 18) free pipeline VMEM
-    (:func:`gauge_headroom_bytes`), nudging the threshold up.
+    ``(T, Z, 24, Y, Xh)`` or ``(nrhs, T, Z, 24, Y, Xh)``.  ``dtype``
+    (or an int itemsize) sizes the tiles: f64 halves the admissible
+    volume versus f32.  Compressed links (``gauge_comps`` < 18) free
+    pipeline VMEM (:func:`gauge_headroom_bytes`), nudging the threshold
+    up.
     """
-    itemsize = dtype if isinstance(dtype, int) else jnp.dtype(dtype).itemsize
-    limit = _FUSED_SCRATCH_LIMIT_BYTES + gauge_headroom_bytes(
-        psi_e_p_shape[-2], psi_e_p_shape[-1], itemsize, gauge_comps)
-    return itemsize * math.prod(psi_e_p_shape) <= limit
+    return fused_scratch_bytes(psi_e_p_shape, dtype) <= fused_scratch_budget(
+        "resident", psi_e_p_shape, dtype, gauge_comps=gauge_comps)
 
 
 # ---------------------------------------------------------------------------
@@ -737,24 +873,21 @@ def stream_ring_bytes(psi_e_p_shape, dtype=jnp.float32,
     """VMEM bytes of the streaming kernel's t-plane ring.
 
     The ring holds ``window`` t-rows of the (batched) odd intermediate —
-    ``window * Z * 24 * nrhs * Y * Xh`` elements — so its size is derived
-    from the actual ``dtype`` and the RHS batch but is *independent of
-    T*: that is the cap-lift.  ``psi_e_p_shape`` as in
+    ``window * Z * 24 * nrhs`` tiled ``(Y, Xh)`` planes — so its size is
+    derived from the actual ``dtype`` and the RHS batch but is
+    *independent of T*: that is the cap-lift.  ``psi_e_p_shape`` as in
     :func:`fused_dhat_fits`.
     """
-    itemsize = dtype if isinstance(dtype, int) else jnp.dtype(dtype).itemsize
     lead = 1 if len(psi_e_p_shape) == 6 else 0
-    per_row = math.prod(psi_e_p_shape) // psi_e_p_shape[lead]
-    return itemsize * window * per_row
+    return (window * fused_scratch_bytes(psi_e_p_shape, dtype)
+            // psi_e_p_shape[lead])
 
 
 def fused_dhat_stream_fits(psi_e_p_shape, dtype=jnp.float32, *,
                            gauge_comps: int = GAUGE_COMPS) -> bool:
     """Whether the streaming kernel's t-plane ring fits the VMEM budget."""
-    itemsize = dtype if isinstance(dtype, int) else jnp.dtype(dtype).itemsize
-    limit = _FUSED_SCRATCH_LIMIT_BYTES + gauge_headroom_bytes(
-        psi_e_p_shape[-2], psi_e_p_shape[-1], itemsize, gauge_comps)
-    return stream_ring_bytes(psi_e_p_shape, dtype) <= limit
+    return stream_ring_bytes(psi_e_p_shape, dtype) <= fused_scratch_budget(
+        "stream", psi_e_p_shape, dtype, gauge_comps=gauge_comps)
 
 
 def fused_dhat_policy(psi_e_p_shape, dtype=jnp.float32, *,
@@ -772,6 +905,12 @@ def fused_dhat_policy(psi_e_p_shape, dtype=jnp.float32, *,
 
     ``gauge_comps`` < 18 moves both thresholds up by the pipeline VMEM
     the compressed gauge blocks free (:func:`gauge_headroom_bytes`).
+
+    Every count is of tiled VMEM (:func:`plane_vmem_bytes`) against the
+    per-kernel limit, so the policy never picks a kernel the TPU
+    compiler refuses.  With ``(Y, Xh)`` planes padded to whole tiles,
+    no f32 Table-1 volume (16^4, 16^3x64, 32^3x64) fits either fused
+    kernel; they run unfused.
     """
     if fused_dhat_fits(psi_e_p_shape, dtype, gauge_comps=gauge_comps):
         return "resident"
@@ -816,11 +955,11 @@ def dhat_stream_traffic_model(Tl: int, Zl: int, Y: int, Xh: int, *,
         "window_rows": window,
         "vmem_ring_bytes": stream_ring_bytes(shape, itemsize,
                                              window=window),
-        "vmem_resident_bytes": itemsize * math.prod(shape),
+        "vmem_resident_bytes": fused_scratch_bytes(shape, itemsize),
     }
 
 
-def _dhat_stream_kernel(par_src, par_out, pc, pzp, pzm, ptp, ptm, psi0,
+def _dhat_stream_kernel(par_ref, pc, pzp, pzm, ptp, ptm, psi0,
                         uo_src, ue_src, ue_zm, ue_tm,
                         ue_out, uo_out, uo_zm, uo_tm,
                         out_ref, ring_ref, *, kappa2: float, Tl: int,
@@ -848,6 +987,8 @@ def _dhat_stream_kernel(par_src, par_out, pc, pzp, pzm, ptp, ptm, psi0,
     s = pl.program_id(0)
     z = pl.program_id(1)
     compute_dtype = out_ref.dtype
+    par_src = ((s - 1) % Tl + z + par_ref[0]) % 2    # row ts
+    par_out = ((s - 3) % Tl + z + par_ref[0]) % 2    # row to
 
     @pl.when(s <= Tl + 1)
     def _produce():
@@ -857,7 +998,7 @@ def _dhat_stream_kernel(par_src, par_out, pc, pzp, pzm, ptp, ptm, psi0,
                          uo_src[:, 0, 0],
                          ue_src[0, 0, 0], ue_src[1, 0, 0],
                          ue_zm[0, 0, 0], ue_tm[0, 0, 0],
-                         par_src[0, 0], 1)
+                         par_src, 1)
         ring_ref[s % window, z] = jnp.stack(acc).astype(compute_dtype)
 
     @pl.when(s >= 3)
@@ -871,7 +1012,7 @@ def _dhat_stream_kernel(par_src, par_out, pc, pzp, pzm, ptp, ptm, psi0,
                          ue_out[:, 0, 0],
                          uo_out[0, 0, 0], uo_out[1, 0, 0],
                          uo_zm[0, 0, 0], uo_tm[0, 0, 0],
-                         par_out[0, 0], 0)
+                         par_out, 0)
         hop2 = jnp.stack(acc).astype(compute_dtype)
         result = _plane(psi0, batched) - compute_dtype.type(kappa2) * hop2
         if batched:
@@ -901,8 +1042,7 @@ def dhat_planar_fused_stream(u_e_p: jnp.ndarray, u_o_p: jnp.ndarray,
     sources ``(nrhs, T, Z, 24, Y, Xh)`` run one kernel with each gauge
     plane fetched once per grid step for the whole block.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     if window < STREAM_WINDOW_ROWS:
         raise ValueError(
             f"stream window needs >= {STREAM_WINDOW_ROWS} rows (3 live "
@@ -912,22 +1052,18 @@ def dhat_planar_fused_stream(u_e_p: jnp.ndarray, u_o_p: jnp.ndarray,
     lead = 1 if batched else 0
     Tl, Zl = psi_e_p.shape[lead], psi_e_p.shape[lead + 1]
     Y, Xh = psi_e_p.shape[-2], psi_e_p.shape[-1]
-    t0, z0 = tz_offset
 
     gauge_comps = u_e_p.shape[3]
     ring_bytes = stream_ring_bytes(psi_e_p.shape, psi_e_p.dtype,
                                    window=window)
-    ring_limit = _FUSED_SCRATCH_LIMIT_BYTES + gauge_headroom_bytes(
-        Y, Xh, psi_e_p.dtype.itemsize, gauge_comps)
+    ring_limit = fused_scratch_budget("stream", psi_e_p.shape,
+                                      psi_e_p.dtype, gauge_comps=gauge_comps)
     if not interpret and ring_bytes > ring_limit:
         raise ValueError(
             f"streaming Dhat ring needs {ring_bytes} B of VMEM "
             f"(> {ring_limit} budget at gauge_comps={gauge_comps}); this "
             "z-plane volume / nrhs needs the unfused apply_dhat_planar "
             "path")
-
-    par = ((jnp.arange(Tl, dtype=jnp.int32)[:, None] + t0)
-           + (jnp.arange(Zl, dtype=jnp.int32)[None, :] + z0)) % 2
 
     if batched:
         sblk = (nrhs, 1, 1, SPINOR_COMPS, Y, Xh)
@@ -947,16 +1083,12 @@ def dhat_planar_fused_stream(u_e_p: jnp.ndarray, u_o_p: jnp.ndarray,
     def g4(im):
         return pl.BlockSpec(gblk4, im)
 
-    def par_spec(im):
-        return pl.BlockSpec((1, 1), im, memory_space=pltpu.SMEM)
-
     # Produce stage reads source row ts = (s-1) % T; consume stage reads
     # output row to = (s-3) % T.  All wraps are modular block indices, so
     # the two out-of-range lead-in/lead-out rows of each stage fetch
     # valid (revisited) blocks and are simply gated off in the kernel.
     in_specs = [
-        par_spec(lambda s, z: ((s - 1) % Tl, z)),            # par @ ts
-        par_spec(lambda s, z: ((s - 3) % Tl, z)),            # par @ to
+        _origin_parity_spec(),
         spec(lambda s, z: ((s - 1) % Tl, z, 0, 0, 0)),       # psi center
         spec(lambda s, z: ((s - 1) % Tl, (z + 1) % Zl, 0, 0, 0)),
         spec(lambda s, z: ((s - 1) % Tl, (z - 1) % Zl, 0, 0, 0)),
@@ -1002,7 +1134,7 @@ def dhat_planar_fused_stream(u_e_p: jnp.ndarray, u_o_p: jnp.ndarray,
             dimension_semantics=("arbitrary", "arbitrary")),
         name="wilson_dhat_fused_stream",
     )
-    return fn(par, par,
+    return fn(_origin_parity(tz_offset),
               psi_e_p, psi_e_p, psi_e_p, psi_e_p, psi_e_p, psi_e_p,
               u_o_p, u_e_p, u_e_p, u_e_p,
               u_e_p, u_o_p, u_o_p, u_o_p)

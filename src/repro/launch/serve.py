@@ -30,6 +30,7 @@ import jax
 
 from repro import api, configs
 from repro.core import evenodd, su3
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import (BatchingPolicy, AdmissionPolicy,
                            HttpServerThread, PropagatorDaemon,
                            SessionPool, encode_array, serve_http)
@@ -202,6 +203,7 @@ def main(argv=None):
                          "exit (CI smoke)")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     daemon = _build_daemon(args)
     daemon.start()
     if args.selftest:

@@ -34,8 +34,9 @@ import jax.numpy as jnp
 
 from repro import api, backends, configs
 from repro.checkpoint.ckpt import Checkpointer
-from repro.core import evenodd, su3, wilson
 from repro.core import solver as _solver
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.problem import HostProblem
 
 
 def _backend_help() -> str:
@@ -143,6 +144,7 @@ def main(argv=None):
     if args.backend == "help":
         _print_backend_info()
         return
+    enable_compile_cache()
 
     inner_dtype = args.inner_dtype or None
     if inner_dtype:
@@ -183,15 +185,14 @@ def main(argv=None):
           f"nrhs={args.nrhs}"
           + (f", inner_dtype={inner_dtype}" if inner_dtype else ""))
 
-    key = jax.random.PRNGKey(args.seed)
-    U = su3.random_gauge(key, lattice.extents)
-    Ue, Uo = evenodd.pack_gauge(U)
-    # Bind once: layout conversion, placement, and policy selection
-    # happen HERE; the session below then reuses one compiled solve for
-    # the whole batch of same-shape solves.
-    matrix = api.WilsonMatrix.bind(Ue, Uo, args.kappa, backend=bspec,
-                                   validate=args.validate,
-                                   fallback=args.fallback)
+    # The gauge and sources are made on the host CPU and only the bound
+    # (native layout) operator goes to the accelerator: see
+    # repro.launch.problem.  Bind once: layout conversion, placement,
+    # and policy selection happen HERE; the session below then reuses
+    # one compiled solve for the whole batch of same-shape solves.
+    problem = HostProblem(lattice.extents, args.kappa, args.seed)
+    matrix = problem.bind(bspec, validate=args.validate,
+                          fallback=args.fallback)
     session = api.SolveSession(matrix, sspec)
     print(f"backend {matrix.backend.name} "
           f"(native domain: {matrix.ops.domain})")
@@ -210,30 +211,14 @@ def main(argv=None):
     cdtype = jnp.complex128 if inner_dtype else jnp.complex64
 
     for i in range(args.n_solves):
-        ke = jax.random.fold_in(key, 100 + i)
-        bshape = ((nrhs,) if nrhs > 1 else ()) + (T, Z, Y, X, 4, 3)
-        eta = (jax.random.normal(ke, bshape)
-               + 1j * jax.random.normal(jax.random.fold_in(ke, 1), bshape)
-               ).astype(cdtype)
-        if nrhs > 1:
-            ee, eo = jax.vmap(evenodd.pack)(eta)
-        else:
-            ee, eo = evenodd.pack(eta)
+        eta, ee, eo = problem.source(i, nrhs if nrhs > 1 else None,
+                                     cdtype)
         t0 = time.time()
         xe, xo, res = session.solve(ee, eo)
         # The residual check is deliberately NOT the session's operator:
         # it re-verifies the solution against the independent full-system
         # reference D_W, so a broken backend can't vouch for itself.
-        if nrhs > 1:
-            xi = jax.vmap(evenodd.unpack)(xe, xo)
-            r = eta - jax.vmap(
-                # repro-lint: allow[R2] independent full-system residual
-                lambda v: wilson.apply_wilson(U, v, args.kappa))(xi)
-        else:
-            xi = evenodd.unpack(xe, xo)
-            # repro-lint: allow[R2] independent full-system residual
-            r = eta - wilson.apply_wilson(U, xi, args.kappa)
-        rel = float(jnp.linalg.norm(r) / jnp.linalg.norm(eta))
+        rel = problem.true_residual(eta, xe, xo)
         dt = time.time() - t0
         vol = T * Z * Y * X
         iters = int(jnp.max(res.iterations))

@@ -17,3 +17,8 @@ def build(mesh):
 
 def sizes():
     return jax.lax.axis_size("x")                         # line 19: R1
+
+
+def x64():
+    from jax.experimental import enable_x64               # line 23: R1
+    return enable_x64(), jax.enable_x64(True)             # line 24: R1

@@ -69,7 +69,7 @@ def test_r1_fires_on_fixture():
                       _fixture("r1_drifted.py"))
     # line 6 flags twice: the drifted module AND the drifted symbol.
     assert hits == [("R1", 6), ("R1", 6), ("R1", 7), ("R1", 13),
-                    ("R1", 14), ("R1", 19)]
+                    ("R1", 14), ("R1", 19), ("R1", 23), ("R1", 24)]
 
 
 def test_r1_kernels_may_import_pallas_but_not_compiler_params():
@@ -327,7 +327,7 @@ def test_j1_catches_precision_roundtrip():
     # round-trips the iterate through a narrower precision each
     # application (downcast + upcast; the downcast is not exempt).
     # Scoped x64 so the complex128 leg of the round-trip is real.
-    from jax.experimental import enable_x64
+    from repro.compat import enable_x64
 
     def sabotage(bops):
         inner = bops.apply_dhat_native

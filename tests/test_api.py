@@ -98,6 +98,32 @@ def test_backend_spec_validation_against_capabilities():
         backends.available_backends()
 
 
+@pytest.mark.parametrize("name", ["pallas", "pallas_fused",
+                                  "pallas_fused_stream"])
+def test_backend_spec_rejects_compiled_f64_pallas(name):
+    """The TPU compiler builds no f64 Pallas kernel: asking for one with
+    the interpreter off fails at spec time instead of degrading."""
+    with pytest.raises(ValueError, match="no compiled f64 kernel"):
+        api.BackendSpec(name, dtype="f64", interpret=False).validated()
+    assert api.BackendSpec(name, dtype="f64",
+                           interpret=True).validated().dtype == "f64"
+    assert api.BackendSpec(name, dtype="f32",
+                           interpret=False).validated().dtype == "f32"
+
+
+def test_escalation_f64_rung_of_compiled_pallas_runs_jnp():
+    """The refinement ladder's f64 rung never asks a compiled Pallas
+    backend for an f64 kernel: it drops to the jnp reference, while the
+    interpreter (off-TPU default) keeps the Pallas backend."""
+    Ue, Uo, _, _ = make_eo(seed=3)
+    for interpret, want in ((False, "jnp"), (True, "pallas")):
+        D = api.WilsonMatrix.bind(
+            Ue, Uo, KAPPA,
+            backend=api.BackendSpec("pallas", interpret=interpret))
+        rung_ops = api.SolveSession(D)._escalation_factory()("f64")
+        assert rung_ops.backend == want, interpret
+
+
 def test_available_backends_sorted_and_backend_info():
     names = backends.available_backends()
     assert names == sorted(names)
@@ -206,7 +232,7 @@ def test_wilson_matrix_from_ops_wraps_bound_backend():
     np.testing.assert_array_equal(
         np.asarray(D(e)), np.asarray(bops.apply_dhat(e, KAPPA)))
     # gauge round-trip for refined solves (c128 needs x64 enabled)
-    from jax.experimental import enable_x64
+    from repro.compat import enable_x64
     with enable_x64():
         U64e, _ = D.gauge_complex()
         assert U64e.dtype == jnp.complex128
@@ -336,7 +362,7 @@ def test_session_requires_matrix():
 def test_session_refined_solve_cached():
     """Mixed-precision refinement through the session: RefinedResult
     contract, correct to the f64 tolerance, one cache entry reused."""
-    from jax.experimental import enable_x64
+    from repro.compat import enable_x64
 
     with enable_x64():
         Ue, Uo, e, o = make_eo(seed=17)
@@ -381,3 +407,79 @@ def test_solve_wilson_eo_shim_is_gone():
 
     assert not hasattr(solver, "solve_wilson_eo")
     assert not hasattr(core, "solve_wilson_eo")
+
+
+# --- host-resident problems (repro.launch.problem) --------------------
+
+_HOST_SOURCES_BODY = """
+import jax, numpy as np
+from jax.sharding import Mesh
+from repro import api
+from repro.launch import problem
+
+devs = jax.devices()
+host, chip = devs[4], devs[0]
+problem.host_device = lambda: host          # 'host' = device 4
+prob = problem.HostProblem((4, 4, 4, 8), 0.13, seed=3)
+eta, ee, eo = prob.source(0)
+assert ee.sharding.device_set == {host}
+spec = api.SolveSpec(method="cgnr", tol=1e-5)
+
+# Planar operator: only the native vector crosses; Dhat comes back home.
+m = prob.bind(api.BackendSpec("pallas_fused", interpret=True),
+              fallback=True)
+assert m.devices() == {chip}, m.devices()
+assert m.foreign_home(ee) == host
+assert m.encode(ee).sharding.device_set == {chip}
+ce, co = jax.device_put((ee, eo), chip)
+assert m.foreign_home(ce) is None
+d = m(ee)
+assert d.sharding.device_set == {host}
+np.testing.assert_array_equal(np.asarray(d), np.asarray(m(ce)))
+# A fallback rebinds from the host-resident exact gauge, and serves
+# from the device the failed matrix was on.
+nxt = m.fallback_next("injected")
+assert nxt.devices() == {chip} and nxt.degraded, nxt.devices()
+
+# Session: solved on the chip, decoded on the host; the same solve with
+# the sources already on the chip gives the same solution.
+j = prob.bind(api.BackendSpec("jnp"))
+assert j.devices() == {chip}
+session = api.SolveSession(j, spec)
+xe, xo, res = session.solve(ee, eo)
+assert xe.sharding.device_set == {host}, xe.sharding
+assert prob.true_residual(eta, xe, xo) <= 1e-5
+ye, yo, _ = session.solve(ce, co)
+assert ye.sharding.device_set == {chip}
+np.testing.assert_array_equal(np.asarray(xe), np.asarray(ye))
+assert session.stats()["traces"] == 1
+
+# A four-device mesh that leaves the host out.
+mesh = Mesh(np.array(devs[:4]).reshape(4, 1), ("data", "model"))
+dist = prob.bind(api.BackendSpec("distributed"), mesh=mesh)
+assert dist.devices() == set(devs[:4])
+dd = dist(ee)
+assert dd.sharding.device_set == {host}
+np.testing.assert_allclose(np.asarray(dd), np.asarray(d), atol=1e-5)
+print("OK")
+"""
+
+
+def test_host_problem_sources_cross_as_native_vectors():
+    """Sources committed to the host are encoded and decoded there; only
+    native vectors reach the operator's device (one device, a fallback
+    rebind, and a four-device mesh that leaves the host out)."""
+    import os
+    import pathlib
+    import subprocess
+    import sys
+
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    env = dict(os.environ, PYTHONPATH=str(repo / "src"))
+    env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=5 "
+                        + env.get("XLA_FLAGS", ""))
+    out = subprocess.run([sys.executable, "-c", _HOST_SOURCES_BODY],
+                         capture_output=True, text=True, env=env,
+                         timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert out.stdout.strip().endswith("OK")

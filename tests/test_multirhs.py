@@ -129,6 +129,30 @@ def test_gauge_loaded_once_per_grid_step(small_eo):
             > 2 * m1["intensity_flops_per_byte"])
 
 
+def test_hop_rhs_blocks_match_whole_block(monkeypatch):
+    """Where the whole RHS block would overflow VMEM, the hop kernel
+    walks it in smaller column blocks on an inner grid axis; every
+    column comes out bit-identical to the one-block kernel."""
+    from repro.kernels import ops
+    from repro.kernels import wilson_stencil as ws
+    Ue, Uo, e, _ = make_batched_eo((4, 4, 4, 8), 4, seed=37)
+    u_e_p, u_o_p = ops.make_planar_fields(Ue, Uo)
+    v = layout.spinor_to_planar(e)
+    Y, Xh = v.shape[-2:]
+
+    def hop(w):
+        return ws.hop_block_planar(u_o_p, u_e_p, w, 1, interpret=True,
+                                   axpy=(0.5, w))
+
+    assert ws.hop_rhs_block(4, Y, Xh, with_axpy=True) == 4
+    whole = hop(v)
+    # A limit that admits two columns per grid step but not four.
+    monkeypatch.setattr(ws, "_VMEM_LIMIT_BYTES",
+                        ws.hop_vmem_bytes(2, Y, Xh, with_axpy=True))
+    assert ws.hop_rhs_block(4, Y, Xh, with_axpy=True) == 2
+    np.testing.assert_array_equal(np.asarray(hop(v)), np.asarray(whole))
+
+
 def test_batched_cg_convergence_mask_freezes():
     """Converged columns freeze: a zero RHS converges at iteration 0 and
     its iterate never moves; scaled columns converge to scaled solutions
@@ -208,7 +232,7 @@ def test_mixed_precision_reaches_f64_tol():
     tolerance the pure-f64 solve reaches, with fewer f64 operator
     applications (counted: CGNR pays ~2/iteration in f64; refinement
     pays ~1 per outer pass)."""
-    from jax.experimental import enable_x64
+    from repro.compat import enable_x64
 
     tol = 1e-10
     with enable_x64():
@@ -251,7 +275,7 @@ def test_fused_dhat_fits_dtype_derived():
     """The scratch-budget check sizes elements by the ACTUAL dtype (and
     accepts batched shapes): a shape that fits in f32 can exceed the
     budget in f64, and a batched block multiplies the scratch by nrhs."""
-    shape = (8, 8, 24, 32, 36)   # 7.1 MiB f32, 14.2 MiB f64
+    shape = (8, 8, 24, 8, 4)     # 6 MiB f32, 12 MiB f64 in (8, 128) tiles
     assert fused_dhat_fits(shape)                      # default f32
     assert fused_dhat_fits(shape, jnp.float32)
     assert not fused_dhat_fits(shape, jnp.float64)
@@ -370,7 +394,7 @@ def test_bf16_inner_converges_where_naive_stalls(monkeypatch):
       ``SolveSpec(inner_dtype="bf16", inner_tol=1e-3)`` refinement
       converges to the f64 tolerance.
     """
-    from jax.experimental import enable_x64
+    from repro.compat import enable_x64
 
     kappa, nrhs, inner_tol = 0.24, 2, 1e-3
     Ue, Uo, e, o = make_batched_eo((4, 4, 4, 8), nrhs, seed=0)

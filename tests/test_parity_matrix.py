@@ -18,114 +18,35 @@ output of the *same* backend within the codec round-trip error) and the
 distributed ``overlap="interior"`` schedule (comms/compute overlap must
 be numerically invisible).
 
-The deterministic matrix below always runs; a hypothesis layer widens
-the lattice/seed space when hypothesis is installed (CI installs it via
-requirements-dev.txt).
+The matrix is spread over several files so the multi-worker test run
+can place its legs on different workers, with the shared harness in
+``parity_harness.py``: the backend x dtype x nrhs matrix and the
+overlap schedule in ``test_parity_backends.py``, the codec legs in
+``test_parity_codecs.py`` and the hypothesis
+layer (which widens the lattice/seed space when hypothesis is installed)
+in ``test_parity_hypothesis.py``.  This file keeps the registry-coverage
+check, the chaos legs and the overlap-with-compression leg.
 """
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
+from parity_harness import (ATOL, COMPRESSIONS, ODD_LATTICE, all_backends,
+                            bind, fields)
 
 from repro import backends
-from repro.core import evenodd, su3
-
-try:
-    from hypothesis import given, settings, strategies as st
-    HAVE_HYPOTHESIS = True
-except ImportError:       # deterministic matrix still runs without it
-    HAVE_HYPOTHESIS = False
-
-DTYPES = ("f32", "f64")
-NRHS = (1, 4)
-# Odd T/Z/Y; X=6 gives odd Xh=3 — every axis wraps mid-parity-pattern.
-ODD_LATTICE = (3, 5, 3, 6)
-
-_PLANAR = {"f32": jnp.float32, "f64": jnp.float64}
-_COMPLEX = {"f32": jnp.complex64, "f64": jnp.complex128}
-_ATOL = {"f32": 5e-5, "f64": 1e-10}
 
 
-def all_backends():
-    return backends.available_backends()
+def _chaos_cases():
+    """Every backend, with and without each gauge codec it declares."""
+    cases = []
+    for name in all_backends():
+        caps = backends.backend_info(name)
+        cases += [(name, c) for c in ("none",) + tuple(
+            c for c in COMPRESSIONS if c in caps.gauge_compressions)]
+    return cases
 
 
-def _bind(name, Ue, Uo, dtype, **extra):
-    opts = {"dtype": _PLANAR[dtype]} if name != "jnp" else {}
-    if name.startswith("pallas") and jax.default_backend() != "tpu":
-        opts["interpret"] = True
-    opts.update(extra)
-    ops = backends.make_wilson_ops(name, Ue, Uo, **opts)
-    if name == "distributed":
-        # Eager shard_map dispatches the body op-by-op (minutes per
-        # Dhat); jit the entry points the matrix exercises.
-        import dataclasses
-        ops = dataclasses.replace(
-            ops,
-            apply_dhat=jax.jit(ops.apply_dhat, static_argnums=1),
-            apply_dhat_dagger=jax.jit(ops.apply_dhat_dagger,
-                                      static_argnums=1),
-            apply_dhat_native_batched=jax.jit(
-                ops.apply_dhat_native_batched, static_argnums=1))
-    return ops
-
-
-def _fields(shape, dtype, nrhs, seed=0):
-    cdt = _COMPLEX[dtype]
-    # Generate the gauge at the target precision: compressed-link
-    # reconstruction relies on unitarity *at that precision*, and an
-    # f32-generated field upcast to f64 is only unitary to ~1e-7.
-    U = su3.random_gauge(jax.random.PRNGKey(seed), shape, dtype=cdt)
-    k = jax.random.PRNGKey(seed + 1)
-    bshape = (nrhs, *shape, 4, 3)
-    psi = (jax.random.normal(k, bshape)
-           + 1j * jax.random.normal(jax.random.fold_in(k, 1), bshape)
-           ).astype(cdt)
-    e, _ = jax.vmap(evenodd.pack)(psi)
-    Ue, Uo = evenodd.pack_gauge(U)
-    return Ue, Uo, e
-
-
-def _check_parity(name, shape, dtype, nrhs, seed=0, **bind_opts):
-    """Dhat / Dhat^dag / batched-Dhat of ``name`` vs the jnp reference."""
-    kappa = 0.13
-    atol = _ATOL[dtype]
-    Ue, Uo, e = _fields(shape, dtype, nrhs, seed=seed)
-    ref = backends.make_wilson_ops("jnp", Ue, Uo)
-    bops = _bind(name, Ue, Uo, dtype, **bind_opts)
-
-    want = jnp.stack([ref.apply_dhat(e[n], kappa) for n in range(nrhs)])
-
-    # Unbatched ops (Dhat and its dagger), column by column — the
-    # nrhs=1 leg of the matrix carries these; the nrhs>1 legs would
-    # repeat byte-identical work and only re-exercise cached kernels.
-    if nrhs == 1:
-        want_dag = jnp.stack(
-            [ref.apply_dhat_dagger(e[n], kappa) for n in range(nrhs)])
-        for n in range(nrhs):
-            np.testing.assert_allclose(
-                np.asarray(bops.apply_dhat(e[n], kappa)),
-                np.asarray(want[n]), atol=atol,
-                err_msg=f"{name} Dhat col {n} {shape} {dtype}")
-            np.testing.assert_allclose(
-                np.asarray(bops.apply_dhat_dagger(e[n], kappa)),
-                np.asarray(want_dag[n]), atol=atol,
-                err_msg=f"{name} Dhat^dag col {n} {shape} {dtype}")
-
-    # Batched native op, whole block at once.
-    v = bops.to_domain_batched(e)
-    got = bops.from_domain_batched(
-        bops.apply_dhat_native_batched(v, kappa)).astype(e.dtype)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=atol,
-                               err_msg=f"{name} batched Dhat {shape} "
-                                       f"{dtype} nrhs={nrhs}")
-
-
-def _x64_ctx(dtype):
-    from jax.experimental import enable_x64
-    import contextlib
-    return enable_x64() if dtype == "f64" else contextlib.nullcontext()
+CHAOS_CASES = _chaos_cases()
 
 
 def test_matrix_covers_every_registered_backend():
@@ -134,58 +55,15 @@ def test_matrix_covers_every_registered_backend():
     backend is registered)."""
     assert "pallas_fused_stream" in all_backends()
     assert "jnp" in all_backends()
-
-
-@pytest.mark.parametrize("nrhs", NRHS)
-@pytest.mark.parametrize("dtype", DTYPES)
-@pytest.mark.parametrize("name", all_backends())
-def test_backend_parity_odd_lattice(name, dtype, nrhs):
-    with _x64_ctx(dtype):
-        _check_parity(name, ODD_LATTICE, dtype, nrhs)
-
-
-# --- compressed gauge links ------------------------------------------
-
-COMPRESSIONS = ("two_row", "minimal")
-# atol vs the *same backend uncompressed* — isolates the codec error
-# from the backend-vs-reference error the matrix above already bounds.
-_C_ATOL = {("two_row", "f32"): 1e-5, ("two_row", "f64"): 1e-12,
-           ("minimal", "f32"): 1e-5, ("minimal", "f64"): 1e-9}
-
-
-@pytest.mark.parametrize("dtype", DTYPES)
-def test_compressed_gauge_parity(dtype):
-    """Every backend that advertises a compressed link representation
-    reproduces its own uncompressed Dhat within the codec round-trip
-    error (capability-gated over the live registry; the uncompressed
-    reference is bound once per backend and shared across codecs)."""
-    kappa = 0.13
-    ran = 0
-    with _x64_ctx(dtype):
-        Ue, Uo, e = _fields(ODD_LATTICE, dtype, 1)
-        for name in all_backends():
-            caps = backends.backend_info(name)
-            modes = [c for c in COMPRESSIONS
-                     if c in caps.gauge_compressions]
-            if not modes:
-                continue
-            plain = _bind(name, Ue, Uo, dtype)
-            want = np.asarray(plain.apply_dhat(e[0], kappa))
-            for compression in modes:
-                comp = _bind(name, Ue, Uo, dtype,
-                             gauge_compression=compression)
-                np.testing.assert_allclose(
-                    np.asarray(comp.apply_dhat(e[0], kappa)), want,
-                    atol=_C_ATOL[compression, dtype],
-                    err_msg=f"{name} {compression} {dtype}")
-                ran += 1
-    assert ran >= 8   # pallas x3 + distributed, two codecs each
+    assert len(CHAOS_CASES) >= 8   # every backend, plus each codec
 
 
 # --- chaos legs: NaN-column containment across backends x codecs -----
 
 
-def test_chaos_nan_column_containment_matrix():
+@pytest.mark.parametrize("name,compression", CHAOS_CASES,
+                         ids=[f"{n}-{c}" for n, c in CHAOS_CASES])
+def test_chaos_nan_column_containment_matrix(name, compression):
     """A NaN injected into one RHS column of the batched solve stays in
     that column on EVERY backend and every supported gauge codec: the
     poisoned column exits ``diverged`` and the healthy columns are
@@ -197,49 +75,28 @@ def test_chaos_nan_column_containment_matrix():
 
     kappa = 0.13
     nrhs = 3
-    ran = 0
-    Ue, Uo, e = _fields(ODD_LATTICE, "f32", nrhs)
+    Ue, Uo, e = fields(ODD_LATTICE, "f32", nrhs)
     e_bad = nan_spinor_column(e, 1)
-    for name in all_backends():
-        caps = backends.backend_info(name)
-        modes = ("none",) + tuple(c for c in COMPRESSIONS
-                                  if c in caps.gauge_compressions)
-        for compression in modes:
-            extra = ({} if compression == "none"
-                     else {"gauge_compression": compression})
-            bops = _bind(name, Ue, Uo, "f32", **extra)
-            run = jax.jit(solver.make_native_solve(
-                bops, kappa, method="cgnr", tol=1e-3, max_iters=12,
-                batched=True))
-            v_o = bops.to_domain_batched(e)
-            _, _, clean = run(bops.to_domain_batched(e), v_o)
-            _, _, res = run(bops.to_domain_batched(e_bad), v_o)
-            tag = f"{name}/{compression}"
-            assert bool(res.diverged[1]), tag
-            assert not bool(res.converged[1]), tag
-            for col in (0, 2):
-                assert np.array_equal(np.asarray(res.x[col]),
-                                      np.asarray(clean.x[col])), \
-                    (f"{tag}: healthy column {col} perturbed by the "
-                     "injected NaN column")
-            ran += 1
-    assert ran >= 8   # every backend, plus each declared codec
+    extra = ({} if compression == "none"
+             else {"gauge_compression": compression})
+    bops = bind(name, Ue, Uo, "f32", **extra)
+    run = jax.jit(solver.make_native_solve(
+        bops, kappa, method="cgnr", tol=1e-3, max_iters=12,
+        batched=True))
+    v_o = bops.to_domain_batched(e)
+    _, _, clean = run(bops.to_domain_batched(e), v_o)
+    _, _, res = run(bops.to_domain_batched(e_bad), v_o)
+    tag = f"{name}/{compression}"
+    assert bool(res.diverged[1]), tag
+    assert not bool(res.converged[1]), tag
+    for col in (0, 2):
+        assert np.array_equal(np.asarray(res.x[col]),
+                              np.asarray(clean.x[col])), \
+            (f"{tag}: healthy column {col} perturbed by the "
+             "injected NaN column")
 
 
-# --- distributed comms/compute overlap -------------------------------
-
-
-@pytest.mark.parametrize("dtype,nrhs", [("f32", 1), ("f32", 4),
-                                        ("f64", 1)])
-def test_distributed_interior_overlap_parity(dtype, nrhs):
-    """The interior/boundary split schedule is numerically identical to
-    the fused schedule (ODD_LATTICE has Tl=3: a one-plane-thick interior
-    — the thinnest legal overlap region).  The f64 leg runs nrhs=1 only:
-    the x64 compile of the split schedule dominates the suite and the
-    batched path is already covered at f32."""
-    with _x64_ctx(dtype):
-        _check_parity("distributed", ODD_LATTICE, dtype, nrhs,
-                      overlap="interior")
+# --- distributed comms/compute overlap, with compression ------------
 
 
 def test_distributed_interior_compressed_parity():
@@ -248,29 +105,9 @@ def test_distributed_interior_compressed_parity():
     — the dagger/batched legs are covered by the two tests above, and
     each extra leg is another ~30s compile of the split schedule)."""
     kappa = 0.13
-    Ue, Uo, e = _fields(ODD_LATTICE, "f32", 1)
+    Ue, Uo, e = fields(ODD_LATTICE, "f32", 1)
     want = backends.make_wilson_ops("jnp", Ue, Uo).apply_dhat(e[0], kappa)
-    bops = _bind("distributed", Ue, Uo, "f32", overlap="interior",
+    bops = bind("distributed", Ue, Uo, "f32", overlap="interior",
                  gauge_compression="two_row")
     np.testing.assert_allclose(np.asarray(bops.apply_dhat(e[0], kappa)),
-                               np.asarray(want), atol=_ATOL["f32"])
-
-
-if HAVE_HYPOTHESIS:
-    settings.register_profile("parity", max_examples=5, deadline=None)
-    settings.load_profile("parity")
-
-    odd_dim = st.sampled_from([2, 3, 5])
-
-    @given(T=odd_dim, Z=odd_dim, Y=st.sampled_from([2, 3]),
-           Xh=st.sampled_from([2, 3]),
-           dtype=st.sampled_from(DTYPES),
-           nrhs=st.sampled_from(NRHS),
-           seed=st.integers(0, 2 ** 12))
-    def test_backend_parity_hypothesis(T, Z, Y, Xh, dtype, nrhs, seed):
-        """Random odd-extent lattices: every backend agrees with the
-        reference on Dhat / Dhat^dag / batched Dhat."""
-        shape = (T, Z, Y, 2 * Xh)
-        with _x64_ctx(dtype):
-            for name in all_backends():
-                _check_parity(name, shape, dtype, nrhs, seed=seed)
+                               np.asarray(want), atol=ATOL["f32"])

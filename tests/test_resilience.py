@@ -25,7 +25,7 @@ SHAPE = (4, 4, 4, 8)
 
 
 def _x64():
-    from jax.experimental import enable_x64
+    from repro.compat import enable_x64
     return enable_x64()
 
 

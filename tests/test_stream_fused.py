@@ -2,14 +2,15 @@
 exact byte boundaries, the cap-lift, and silent-correct fallback.
 
 The resident fused kernel's scratch is the whole (batched) odd
-intermediate — ``itemsize * nrhs * 24 * T*Z*Y*Xh`` bytes against a 12 MiB
-budget.  The streaming kernel replaces it with a 4-row t-plane ring whose
-size is independent of T.  These tests pin the selection policy
-(resident -> stream -> unfused) at shapes exactly at / one plane over the
-budget for f32/f64/bf16 and nrhs in {1, 8}, and that every path computes
-the same operator.
+intermediate — ``nrhs * 24 * T * Z`` planes of ``(Y, Xh)``, each padded to
+whole (sublane, 128-lane) VMEM tiles — against what the per-kernel VMEM
+limit leaves after the pipeline blocks.  The streaming kernel replaces it
+with a 4-row t-plane ring whose size is independent of T.  These tests
+pin the tiled counts, the selection policy (resident -> stream ->
+unfused) at the last shape that fits and one plane over, for
+f32/f64/bf16 and nrhs in {1, 2}, and that every path computes the same
+operator.
 """
-import math
 
 import jax
 import jax.numpy as jnp
@@ -25,32 +26,37 @@ from repro.kernels.wilson_stencil import (
     fused_dhat_fits, fused_dhat_policy, fused_dhat_stream_fits,
     stream_ring_bytes)
 
-LIMIT = ws._FUSED_SCRATCH_LIMIT_BYTES
 KAPPA = 0.13
 
-ITEMSIZE = {"f32": 4, "f64": 8, "bf16": 2}
 DTYPE = {"f32": jnp.float32, "f64": jnp.float64, "bf16": jnp.bfloat16}
+# VMEM bytes of one (8, 4) plane: a whole (8, 128) tile of 32-bit words
+# for f32, (16, 128) for bf16, (8, 128) of 64-bit words for f64.
+Y, XH = 8, 4
+PLANE = {"f32": 4096, "bf16": 4096, "f64": 8192}
 
 
-def _resident_boundary_shape(itemsize, nrhs):
-    """Planar shape whose resident scratch is EXACTLY the budget."""
-    sites = LIMIT // (24 * itemsize * nrhs)     # = T*Z*Y*Xh at the budget
-    T, Z, Y = 16, 16, 8
-    Xh = sites // (T * Z * Y)
-    assert T * Z * Y * Xh == sites, (itemsize, nrhs)
-    shape = (T, Z, 24, Y, Xh)
+def _batched(shape, nrhs):
     return (nrhs, *shape) if nrhs > 1 else shape
 
 
-def _stream_boundary_shape(itemsize, nrhs):
-    """Planar shape whose 4-row ring is EXACTLY the budget (T far too
-    large for the resident scratch)."""
-    row_sites = LIMIT // (24 * itemsize * nrhs * STREAM_WINDOW_ROWS)
-    T, Z, Y = 64, 16, 8
-    Xh = row_sites // (Z * Y)
-    assert Z * Y * Xh == row_sites, (itemsize, nrhs)
-    shape = (T, Z, 24, Y, Xh)
-    return (nrhs, *shape) if nrhs > 1 else shape
+def _resident_boundary_shape(dt, nrhs):
+    """Planar shape with the most t-rows whose resident scratch fits.
+
+    One z-plane per t-row keeps a row small enough that the 4-row ring
+    still fits where the resident scratch stops, in every dtype."""
+    probe = _batched((1, 1, 24, Y, XH), nrhs)
+    budget = ws.fused_scratch_budget("resident", probe, DTYPE[dt])
+    T = budget // (24 * nrhs * PLANE[dt])
+    return _batched((T, 1, 24, Y, XH), nrhs)
+
+
+def _stream_boundary_shape(dt, nrhs):
+    """Planar shape with the most z-planes whose 4-row ring fits (T far
+    too large for the resident scratch)."""
+    probe = _batched((64, 1, 24, Y, XH), nrhs)
+    budget = ws.fused_scratch_budget("stream", probe, DTYPE[dt])
+    Z = budget // (STREAM_WINDOW_ROWS * 24 * nrhs * PLANE[dt])
+    return _batched((64, Z, 24, Y, XH), nrhs)
 
 
 def _bump(shape, axis_from_t):
@@ -60,14 +66,17 @@ def _bump(shape, axis_from_t):
     return shape[:i] + (shape[i] + 1,) + shape[i + 1:]
 
 
-@pytest.mark.parametrize("nrhs", [1, 8])
+@pytest.mark.parametrize("nrhs", [1, 2])
 @pytest.mark.parametrize("dt", ["f32", "f64", "bf16"])
 def test_resident_boundary_exact_and_one_plane_over(dt, nrhs):
-    """At the budget: resident.  One t-plane over: the resident scratch
-    no longer fits but the ring trivially does -> stream."""
-    item = ITEMSIZE[dt]
-    shape = _resident_boundary_shape(item, nrhs)
-    assert item * math.prod(shape) == LIMIT
+    """At the last t-row that fits: resident.  One t-plane over: the
+    resident scratch no longer fits but the ring does -> stream."""
+    shape = _resident_boundary_shape(dt, nrhs)
+    T = shape[-5]
+    assert T > STREAM_WINDOW_ROWS
+    assert ws.plane_vmem_bytes(Y, XH, DTYPE[dt]) == PLANE[dt]
+    assert ws.fused_scratch_bytes(shape, DTYPE[dt]) == (
+        T * 24 * nrhs * PLANE[dt])
     assert fused_dhat_fits(shape, DTYPE[dt])
     assert fused_dhat_policy(shape, DTYPE[dt]) == "resident"
 
@@ -77,14 +86,17 @@ def test_resident_boundary_exact_and_one_plane_over(dt, nrhs):
     assert fused_dhat_policy(over, DTYPE[dt]) == "stream"
 
 
-@pytest.mark.parametrize("nrhs", [1, 8])
+@pytest.mark.parametrize("nrhs", [1, 2])
 @pytest.mark.parametrize("dt", ["f32", "f64", "bf16"])
 def test_stream_boundary_exact_and_one_plane_over(dt, nrhs):
-    """At the budget the ring fits -> stream; one z-plane over it cannot
-    (the ring holds full z-rows) -> the silent two-kernel fallback."""
-    item = ITEMSIZE[dt]
-    shape = _stream_boundary_shape(item, nrhs)
-    assert stream_ring_bytes(shape, DTYPE[dt]) == LIMIT
+    """At the last z-plane that fits the ring -> stream; one z-plane
+    over it cannot (the ring holds full z-rows) -> the silent two-kernel
+    fallback."""
+    shape = _stream_boundary_shape(dt, nrhs)
+    Z = shape[-4]
+    assert Z >= 1
+    assert stream_ring_bytes(shape, DTYPE[dt]) == (
+        STREAM_WINDOW_ROWS * Z * 24 * nrhs * PLANE[dt])
     assert not fused_dhat_fits(shape, DTYPE[dt])
     assert fused_dhat_policy(shape, DTYPE[dt]) == "stream"
 
@@ -99,20 +111,26 @@ def test_ring_bytes_independent_of_t():
     base = (8, 4, 24, 4, 4)
     tall = (512, 4, 24, 4, 4)
     assert stream_ring_bytes(base) == stream_ring_bytes(tall)
-    assert (4 * math.prod(tall)) == 64 * (4 * math.prod(base))
+    assert ws.fused_scratch_bytes(tall) == 64 * ws.fused_scratch_bytes(base)
     # Batched shapes scale the ring by nrhs, like the resident scratch.
     assert stream_ring_bytes((8, *base)) == 8 * stream_ring_bytes(base)
 
 
 def test_acceptance_lattice_runs_streaming_policy():
-    """16x16x16x32 at nrhs=8, f32 — the ISSUE's canonical cap casualty:
-    the resident scratch (~50 MiB) fails, the ring is exactly 12 MiB."""
-    shape = (8, 16, 16, 24, 16, 16)             # planar, batched
-    assert not fused_dhat_fits(shape, jnp.float32)
-    assert stream_ring_bytes(shape, jnp.float32) == LIMIT
-    assert fused_dhat_policy(shape, jnp.float32) == "stream"
-    # ...and f64 doubles the ring past the budget -> unfused fallback.
-    assert fused_dhat_policy(shape, jnp.float64) == "unfused"
+    """16x16x16x32: its (16, 16) planes take (16, 128) tiles, 8 KiB in
+    f32 and 4 KiB in bf16.  One bf16 right-hand side streams (6 MiB
+    ring); in f32 the 12 MiB ring plus the pipeline passes the 16 MiB
+    limit, and at nrhs=8 the ring alone is 96 MiB -> unfused."""
+    shape = (16, 16, 24, 16, 16)                # planar
+    assert stream_ring_bytes(shape, jnp.float32) == 12 << 20
+    assert stream_ring_bytes(shape, jnp.bfloat16) == 6 << 20
+    assert fused_dhat_policy(shape, jnp.bfloat16) == "stream"
+    assert fused_dhat_policy(shape, jnp.float32) == "unfused"
+    batched = (8, *shape)
+    assert not fused_dhat_fits(batched, jnp.float32)
+    assert stream_ring_bytes(batched, jnp.float32) == 96 << 20
+    assert fused_dhat_policy(batched, jnp.float32) == "unfused"
+    assert fused_dhat_policy(batched, jnp.float64) == "unfused"
 
 
 def test_stream_traffic_model_accounts_overhead():
@@ -169,19 +187,21 @@ def test_auto_policy_routes_over_budget_lattice_to_stream(monkeypatch):
     """A lattice that fails ``fused_dhat_fits`` must run the STREAMING
     kernel under the auto policy (not the two-kernel fallback), and still
     match the jnp reference — the cap-lift acceptance shape in miniature
-    (the budget is shrunk instead of the lattice grown; the policy reads
-    the live module constant).  T=8 > the 4-row window, so the ring is
-    strictly smaller than the resident scratch."""
-    Ue, Uo, e = _rand_planar((8, 2, 2, 4), seed=23)
+    (the VMEM limit is set so the ring just fits instead of the lattice
+    grown; the policy reads the live module constant).  T=16 is four
+    times the 4-row window, so the ring saves more than the streaming
+    kernel's larger pipeline costs."""
+    Ue, Uo, e = _rand_planar((16, 2, 2, 4), seed=23)
     kappa = KAPPA
     ref = backends.make_wilson_ops("jnp", Ue, Uo)
     Uep, Uop = ops.make_planar_fields(Ue, Uo)
     ep = layout.spinor_to_planar(e)
-    # Budget below the resident scratch but above the 4-row ring.
-    resident = 4 * math.prod(ep.shape)
+    # Limit at which the ring fits exactly; the resident scratch not.
     ring = stream_ring_bytes(ep.shape)
-    assert ring < resident
-    monkeypatch.setattr(ws, "_FUSED_SCRATCH_LIMIT_BYTES", ring)
+    assert ring < ws.fused_scratch_bytes(ep.shape)
+    monkeypatch.setattr(ws, "_VMEM_LIMIT_BYTES", ws._VMEM_LIMIT_BYTES + ring
+                        - ws.fused_scratch_budget("stream", ep.shape))
+    assert not fused_dhat_fits(ep.shape, ep.dtype)
     assert fused_dhat_policy(ep.shape, ep.dtype) == "stream"
 
     jaxpr = str(jax.make_jaxpr(
@@ -205,7 +225,7 @@ def test_auto_policy_unfused_fallback_is_silent_correct(monkeypatch,
     ref = backends.make_wilson_ops("jnp", Ue, Uo)
     Uep, Uop = ops.make_planar_fields(Ue, Uo)
     ep = layout.spinor_to_planar(e)
-    monkeypatch.setattr(ws, "_FUSED_SCRATCH_LIMIT_BYTES", 1)
+    monkeypatch.setattr(ws, "_VMEM_LIMIT_BYTES", 1)
     assert fused_dhat_policy(ep.shape, ep.dtype) == "unfused"
     got = layout.spinor_from_planar(
         ops.apply_dhat_planar_any(Uep, Uop, ep, kappa, interpret=True))
@@ -217,7 +237,7 @@ def test_auto_policy_unfused_fallback_is_silent_correct(monkeypatch,
 def test_stream_kernel_rejects_over_budget_ring_off_interpret():
     """On real hardware an over-budget ring must fail loudly, before any
     lowering (mirrors the resident kernel's guard)."""
-    Z, Y, Xh = 17, 32, 64                       # ring 4*Z*24*Y*Xh*4 > 12MiB
+    Z, Y, Xh = 17, 32, 64                       # ring 4*Z*24*16 KiB > 16MiB
     ep = jnp.zeros((8, Z, 24, Y, Xh), jnp.float32)
     u = jnp.zeros((4, 8, Z, 18, Y, Xh), jnp.float32)
     assert not fused_dhat_stream_fits(ep.shape)

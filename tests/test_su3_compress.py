@@ -37,7 +37,7 @@ def _links(n=64, seed=0, dtype=jnp.complex64):
 
 
 def _x64():
-    from jax.experimental import enable_x64
+    from repro.compat import enable_x64
     return enable_x64()
 
 
@@ -171,12 +171,13 @@ def test_gauge_headroom_and_fits_boundary():
     the bytes they free in the double-buffered gauge window; gc=18 is a
     strict no-op on every policy boundary."""
     Y, Xh, itemsize = 4, 4, 4
+    plane = 4096                  # a (4, 4) f32 plane fills an (8, 128) tile
     assert ws.gauge_headroom_bytes(Y, Xh, itemsize, gauge_comps=18) == 0
     for gc in (12, 8):
         head = ws.gauge_headroom_bytes(Y, Xh, itemsize, gauge_comps=gc)
-        assert head == (18 - gc) * 12 * 2 * Y * Xh * itemsize
-        limit = ws._FUSED_SCRATCH_LIMIT_BYTES
-        row = itemsize * 4 * 24 * Y * Xh
+        assert head == (18 - gc) * 12 * 2 * plane
+        limit = ws.fused_scratch_budget("resident", (1, 4, 24, Y, Xh))
+        row = 4 * 24 * plane
         T_plain = limit // row
         T_gc = (limit + head) // row
         assert T_gc > T_plain     # the cap actually moved
