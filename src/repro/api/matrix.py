@@ -262,7 +262,9 @@ class WilsonMatrix:
             return None
         return next(iter(home))
 
-    def _place(self, v):
+    def place(self, v):
+        """Native vector ``v`` moved to the operator's device, where the
+        operator sits on one device and ``v`` is not there."""
         here = self.devices()
         if len(here) == 1 and v.sharding.device_set != here:
             return jax.device_put(v, next(iter(here)))
@@ -366,10 +368,10 @@ class WilsonMatrix:
 
         A source committed to a device the operator is not on (see
         :meth:`foreign_home`) is converted there; only the native vector
-        moves to the operator's device."""
+        moves to the operator's device (:meth:`place`)."""
         v = (self.ops.to_domain_batched(psi) if self._batched(psi)
              else self.ops.to_domain(psi))
-        return v if self.foreign_home(psi) is None else self._place(v)
+        return v if self.foreign_home(psi) is None else self.place(v)
 
     def decode(self, v, dtype=jnp.complex64):
         """Native vector -> complex spinor."""

@@ -17,17 +17,21 @@ recycle-deflated key shows its iterations *dropping* across a request
 stream), and the resilience ledger — backend fallbacks taken, the
 ``degraded`` flag, and per-refined-key outer-iteration /
 precision-escalation histories.
+
+Each solve is a ``repro.solve`` span (:mod:`repro.tracing`), and the
+per-key times are that span's lengths.  While a profiler trace is
+active, a plain solve also records its phases as child spans: encode,
+``h2d`` (sources on another device only), ``exec``, ``d2h`` and decode.
 """
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from repro import backends
+from repro import backends, tracing
 from repro.core import solver as _solver
 from repro.kernels.wilson_stencil import resolve_interpret
 
@@ -37,6 +41,10 @@ from .specs import SolveSpec
 __all__ = ["SolveSession"]
 
 
+def _nbytes(*trees) -> int:
+    return sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(trees))
+
+
 class _CacheEntry:
     __slots__ = ("fn", "kind", "times", "outer", "escalations",
                  "iterations", "col_iterations", "deflation")
@@ -44,7 +52,7 @@ class _CacheEntry:
     def __init__(self, fn, kind, deflation=None):
         self.fn = fn
         self.kind = kind          # "plain" | "refined"
-        self.times = []           # per-solve wall seconds, in call order
+        self.times = []           # repro.solve span seconds, in call order
         self.outer = []           # refined: outer iterations per solve
         self.escalations = []     # refined: dtype rungs climbed per solve
         self.iterations = []      # plain: max Krylov iterations per solve
@@ -121,37 +129,20 @@ class SolveSession:
             batched = eta_e.ndim == 7
         key = (spec, tuple(eta_e.shape), str(eta_e.dtype))
 
-        t0 = time.perf_counter()
-        entry = self._cache.get(key)
-        hit = entry is not None
-        if entry is None:
-            entry = self._build(spec, batched)
+        columns = int(eta_e.shape[0]) if batched else 1
+        with tracing.span("repro.solve", columns=columns) as solve_span:
+            entry = self._cache.get(key)
+            hit = entry is not None
+            if entry is None:
+                entry = self._build(spec, batched)
 
-        x_native = None
-        if entry.kind == "refined":
-            xi_e, xi_o, res = entry.fn(eta_e, eta_o)
-        else:
-            ops = self.matrix.ops
-            # Sources committed to another device than the operator's
-            # (the host, for an accelerator-resident matrix) are encoded
-            # and decoded there; only native vectors cross.
-            home = self.matrix.foreign_home(eta_e)
-            v_e, v_o = self.matrix.encode(eta_e), self.matrix.encode(eta_o)
-            if entry.deflation is not None:
-                x, v_xi_o, res = entry.fn(v_e, v_o,
-                                          entry.deflation.basis)
+            x_native = None
+            if entry.kind == "refined":
+                xi_e, xi_o, res = entry.fn(eta_e, eta_o)
             else:
-                x, v_xi_o, res = entry.fn(v_e, v_o)
-            x_native = x
-            if home is not None:
-                x, v_xi_o = jax.device_put((x, v_xi_o), home)
-            from_dom = (ops.from_domain_batched if batched
-                        else ops.from_domain)
-            # Decode keeps the caller's spinor dtype (c128 under x64).
-            xi_e = from_dom(x).astype(eta_e.dtype)
-            xi_o = from_dom(v_xi_o).astype(eta_o.dtype)
-            res = res._replace(x=xi_e)
-        jax.block_until_ready((xi_e, xi_o))
+                xi_e, xi_o, res, x_native = self._run_plain(
+                    entry, eta_e, eta_o, batched)
+            jax.block_until_ready((xi_e, xi_o))
 
         # Commit cache + counters only after the run succeeded: a build
         # or execution failure (refined spec without x64, an injected
@@ -172,9 +163,47 @@ class SolveSession:
                 # not just the batch maximum.
                 entry.col_iterations.append(
                     [int(i) for i in res.iterations])
-        entry.times.append(time.perf_counter() - t0)
+        entry.times.append(solve_span.seconds)
         self._maybe_harvest(entry, x_native, res, batched)
         return xi_e, xi_o, res
+
+    def _run_plain(self, entry, eta_e, eta_o, batched):
+        """Encode, move, solve, move back and decode, each phase a span
+        of the enclosing ``repro.solve``; returns ``(xi_e, xi_o, res,
+        x_native)``."""
+        matrix, ops = self.matrix, self.matrix.ops
+        # Sources committed to another device than the operator's (the
+        # host, for an accelerator-resident matrix) are encoded and
+        # decoded there; only native vectors cross.
+        home = matrix.foreign_home(eta_e)
+        to_dom = ops.to_domain_batched if batched else ops.to_domain
+        with tracing.span("repro.solve.encode") as sp:
+            v_e, v_o = to_dom(eta_e), to_dom(eta_o)
+            sp.fence(v_e, v_o)
+        if home is not None:
+            with tracing.span("repro.solve.h2d",
+                              bytes=_nbytes(v_e, v_o)) as sp:
+                v_e, v_o = matrix.place(v_e), matrix.place(v_o)
+                sp.fence(v_e, v_o)
+        args = (v_e, v_o)
+        if entry.deflation is not None:
+            args += (entry.deflation.basis,)
+        with tracing.span("repro.solve.exec") as sp:
+            x, v_xi_o, res = entry.fn(*args)
+            sp.fence(x, v_xi_o, res)
+        x_native = x
+        if home is not None:
+            with tracing.span("repro.solve.d2h",
+                              bytes=_nbytes(x, v_xi_o)) as sp:
+                x, v_xi_o = jax.device_put((x, v_xi_o), home)
+                sp.fence(x, v_xi_o)
+        from_dom = ops.from_domain_batched if batched else ops.from_domain
+        with tracing.span("repro.solve.decode") as sp:
+            # Decode keeps the caller's spinor dtype (c128 under x64).
+            xi_e = from_dom(x).astype(eta_e.dtype)
+            xi_o = from_dom(v_xi_o).astype(eta_o.dtype)
+            sp.fence(xi_e, xi_o)
+        return xi_e, xi_o, res._replace(x=xi_e), x_native
 
     def solve_block(self, eta_e, eta_o, spec: Optional[SolveSpec] = None,
                     *, donate: bool = False, bounds=None):

@@ -12,6 +12,10 @@ so that the next move is fixed in one place:
 * the scoped x64 context, ``jax.enable_x64`` (was
   ``jax.experimental.enable_x64``).
 
+It also holds the profiler's own "is a trace being recorded" flag,
+``profiler_active``, which JAX exposes only through the ``TraceMe``
+base class of ``jax.profiler.TraceAnnotation``.
+
 Lint rule R1 keeps everything else off the moved names.
 """
 from __future__ import annotations
@@ -23,7 +27,7 @@ import jax
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["shard_map", "tpu_compiler_params", "make_mesh", "axis_size",
-           "enable_x64"]
+           "enable_x64", "profiler_active"]
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
@@ -70,3 +74,10 @@ def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
 def enable_x64(new_val: bool = True):
     """Context manager that turns 64-bit dtypes on (or off) inside it."""
     return jax.enable_x64(new_val)
+
+
+def profiler_active() -> bool:
+    """True exactly between ``jax.profiler.start_trace`` and
+    ``stop_trace`` (or while a profiler client captures this process):
+    the flag ``TraceMe`` checks before recording an event (~0.2 us)."""
+    return jax.profiler.TraceAnnotation.is_enabled()

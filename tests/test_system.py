@@ -42,6 +42,9 @@ def test_solver_driver_cli(tmp_path, capsys):
                 "--ckpt-dir", str(tmp_path), "--n-solves", "1"])
     out = capsys.readouterr().out
     assert "solve 0:" in out and "done" in out
+    # The solve's time and rate leave out the host reference check,
+    # which the line reports on its own.
+    assert "GFlop/s sustained; reference check" in out
     from repro.checkpoint.ckpt import Checkpointer
     assert Checkpointer(str(tmp_path)).latest_step() == 0
 
