@@ -22,6 +22,12 @@ Each solve is a ``repro.solve`` span (:mod:`repro.tracing`), and the
 per-key times are that span's lengths.  While a profiler trace is
 active, a plain solve also records its phases as child spans: encode,
 ``h2d`` (sources on another device only), ``exec``, ``d2h`` and decode.
+
+Host sources of a planar operator on one device cross as lane-dense
+float32 views of their own complex64 bytes
+(:func:`repro.kernels.layout.spinor_lanes`): the codec's transposes run
+inside the cached executable, on the operator's device, and the host
+only reinterprets bytes.
 """
 from __future__ import annotations
 
@@ -33,6 +39,7 @@ import jax.numpy as jnp
 
 from repro import backends, tracing
 from repro.core import solver as _solver
+from repro.kernels import layout
 from repro.kernels.wilson_stencil import resolve_interpret
 
 from .matrix import WilsonMatrix
@@ -46,11 +53,12 @@ def _nbytes(*trees) -> int:
 
 
 class _CacheEntry:
-    __slots__ = ("fn", "kind", "times", "outer", "escalations",
+    __slots__ = ("fn", "lanes_fn", "kind", "times", "outer", "escalations",
                  "iterations", "col_iterations", "deflation")
 
-    def __init__(self, fn, kind, deflation=None):
+    def __init__(self, fn, kind, deflation=None, lanes_fn=None):
         self.fn = fn
+        self.lanes_fn = lanes_fn  # plain: the same solve on lane-dense views
         self.kind = kind          # "plain" | "refined"
         self.times = []           # repro.solve span seconds, in call order
         self.outer = []           # refined: outer iterations per solve
@@ -73,7 +81,9 @@ class SolveSession:
         print(session.stats())
 
     Plain solves are jitted whole (encode/decode stay outside the
-    executable, at the native-domain boundary); the trace counter in
+    executable, at the native-domain boundary, except for host sources
+    that cross as lane-dense views, whose transposes run inside it:
+    ``stats()["lane_dense_solves"]`` counts those); the trace counter in
     :meth:`stats` increments inside the traced function, so it counts
     *actual* retraces, including any the cache failed to prevent.
     Mixed-precision refined solves (``SolveSpec.inner_dtype``) cache a
@@ -93,7 +103,8 @@ class SolveSession:
         self.default_spec = spec if spec is not None else SolveSpec()
         self._cache = {}
         self._counters = {"solves": 0, "traces": 0, "cache_hits": 0,
-                          "cache_misses": 0, "fallbacks": 0}
+                          "cache_misses": 0, "fallbacks": 0,
+                          "lane_dense_solves": 0}
 
     # --- solve --------------------------------------------------------
 
@@ -134,14 +145,20 @@ class SolveSession:
             entry = self._cache.get(key)
             hit = entry is not None
             if entry is None:
-                entry = self._build(spec, batched)
+                entry = self._build(spec, batched, key[1])
 
             x_native = None
+            lane_home = None
             if entry.kind == "refined":
                 xi_e, xi_o, res = entry.fn(eta_e, eta_o)
             else:
-                xi_e, xi_o, res, x_native = self._run_plain(
-                    entry, eta_e, eta_o, batched)
+                lane_home = self._lane_home(eta_e, eta_o)
+                if lane_home is None:
+                    xi_e, xi_o, res, x_native = self._run_plain(
+                        entry, eta_e, eta_o, batched)
+                else:
+                    xi_e, xi_o, res, x_native = self._run_lanes(
+                        entry, eta_e, eta_o, lane_home)
             jax.block_until_ready((xi_e, xi_o))
 
         # Commit cache + counters only after the run succeeded: a build
@@ -151,6 +168,7 @@ class SolveSession:
         self._cache[key] = entry
         self._counters["cache_hits" if hit else "cache_misses"] += 1
         self._counters["solves"] += 1
+        self._counters["lane_dense_solves"] += lane_home is not None
         if entry.kind == "refined":
             entry.outer.append(int(res.outer_iterations))
             entry.escalations.append(tuple(res.escalations))
@@ -204,6 +222,51 @@ class SolveSession:
             xi_o = from_dom(v_xi_o).astype(eta_o.dtype)
             sp.fence(xi_e, xi_o)
         return xi_e, xi_o, res._replace(x=xi_e), x_native
+
+    def _lane_home(self, eta_e, eta_o):
+        """The home of host sources that cross as lane-dense views, else
+        ``None``: both parities complex64 on one host CPU device, tiling
+        whole, for a planar operator on one other device."""
+        matrix = self.matrix
+        home = matrix.foreign_home(eta_e)
+        if (home is None or home.platform != "cpu"
+                or matrix.foreign_home(eta_o) != home
+                or len(matrix.devices()) != 1
+                or matrix.domain != "planar"
+                or eta_o.dtype != eta_e.dtype
+                or not layout.lanes_fit(eta_e.shape, eta_e.dtype)):
+            return None
+        return home
+
+    def _run_lanes(self, entry, eta_e, eta_o, home):
+        """:meth:`_run_plain` for host sources that cross as lane-dense
+        float32 views of their own bytes: encode and decode only
+        reinterpret bytes on the host, and the cached executable
+        transposes on the operator's device.  The planar solution
+        (``x_native``, for recycling) stays there."""
+        (device,) = self.matrix.devices()
+        with tracing.span("repro.solve.encode"):
+            l_e, l_o = layout.spinor_lanes(eta_e), layout.spinor_lanes(eta_o)
+        with tracing.span("repro.solve.h2d",
+                          bytes=_nbytes(l_e, l_o)) as sp:
+            l_e, l_o = jax.device_put((l_e, l_o), device)
+            sp.fence(l_e, l_o)
+        args = (l_e, l_o)
+        if entry.deflation is not None:
+            args += (entry.deflation.basis,)
+        with tracing.span("repro.solve.exec") as sp:
+            y_e, y_o, res = entry.lanes_fn(*args)
+            sp.fence(y_e, y_o, res)
+        with tracing.span("repro.solve.d2h",
+                          bytes=_nbytes(y_e, y_o)) as sp:
+            y_e, y_o = jax.device_put((y_e, y_o), home)
+            sp.fence(y_e, y_o)
+        with tracing.span("repro.solve.decode") as sp:
+            xi_e, xi_o = jax.device_put(
+                (layout.spinor_from_lanes(y_e, eta_e.shape),
+                 layout.spinor_from_lanes(y_o, eta_o.shape)), home)
+            sp.fence(xi_e, xi_o)
+        return xi_e, xi_o, res._replace(x=xi_e), res.x
 
     def solve_block(self, eta_e, eta_o, spec: Optional[SolveSpec] = None,
                     *, donate: bool = False, bounds=None):
@@ -292,7 +355,8 @@ class SolveSession:
 
         return factory
 
-    def _build(self, spec: SolveSpec, batched: bool) -> _CacheEntry:
+    def _build(self, spec: SolveSpec, batched: bool,
+               shape: tuple) -> _CacheEntry:
         if spec.inner_dtype is not None:
             # Mixed-precision refinement: the bound matrix IS the inner
             # backend (bind it at the inner dtype); the f64 reference
@@ -331,6 +395,9 @@ class SolveSession:
             deflated=deflation is not None)
         counters = self._counters
 
+        to_dom = (self.matrix.ops.to_domain_batched if batched
+                  else self.matrix.ops.to_domain)
+
         if deflation is not None:
             def counted(v_e, v_o, basis):
                 counters["traces"] += 1
@@ -342,13 +409,24 @@ class SolveSession:
                 counters["traces"] += 1
                 return native(v_e, v_o)
 
+        def lanes(l_e, l_o, *basis):
+            # The same solve on lane-dense views of complex64 sources of
+            # ``shape`` (see _run_lanes), planar in between.
+            dtype = jax.eval_shape(
+                to_dom, jax.ShapeDtypeStruct(shape, jnp.complex64)).dtype
+            x, v_xi_o, res = counted(
+                layout.planar_from_lanes(l_e, shape, dtype),
+                layout.planar_from_lanes(l_o, shape, dtype), *basis)
+            return (layout.lanes_from_planar(x),
+                    layout.lanes_from_planar(v_xi_o), res)
+
         # donate_rhs: the encoded source vectors (argnums 0/1; a
         # deflation basis argument is never donated) are handed to XLA
         # for reuse — the serving hot path's batch temporaries.
         # Platforms without donation support warn and run undonated.
         jit_kw = {"donate_argnums": (0, 1)} if spec.donate_rhs else {}
         return _CacheEntry(jax.jit(counted, **jit_kw), "plain",
-                           deflation)
+                           deflation, lanes_fn=jax.jit(lanes, **jit_kw))
 
     # --- observability ------------------------------------------------
 

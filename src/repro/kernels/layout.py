@@ -19,10 +19,23 @@ batch dims, so a block of right-hand sides ``(nrhs, T, Z, Y, Xh, 4, 3)``
 maps to the batched planar layout ``(nrhs, T, Z, 24, Y, Xh)`` — the
 layout the batched kernels eat while loading each gauge plane once for
 the whole block.
+
+Lane-dense spinors: the bytes of a complex64 ``(..., T, Z, Y, Xh, 4, 3)``
+array already hold the interleaved reals ``(..., T, Z, Y, Xh, 24)`` in
+planar component order, so its float32 view, read as ``(rows, 128)``,
+is the planar vector up to one transpose.  :func:`spinor_lanes` takes
+that view on the host without a pass over the data;
+:func:`planar_from_lanes` and :func:`lanes_from_planar` are the
+transposes, traceable, for the device side.  A ``(rows, 128)`` float32
+array whose size fills whole ``(8, 128)`` tiles is stored unpadded on a
+TPU, where a complex ``(..., 4, 3)`` one is not.
 """
 from __future__ import annotations
 
+import math
+
 import jax.numpy as jnp
+import numpy as np
 
 SPINOR_COMPS = 24  # 4 spin x 3 color x re/im
 GAUGE_COMPS = 18   # 3 x 3 x re/im
@@ -60,6 +73,46 @@ def spinor_from_planar(p: jnp.ndarray, dtype=jnp.complex64) -> jnp.ndarray:
     arr = p.astype(_real_dtype_of(dtype)).reshape(*batch, T, Z, 4, 3, 2, Y, Xh)
     arr = jnp.moveaxis(arr, (-2, -1), (-5, -4))       # (...,T,Z,Y,Xh,4,3,2)
     return (arr[..., 0] + 1j * arr[..., 1]).astype(dtype)
+
+
+LANES = 128
+#: float32 elements in one (8, 128) TPU tile
+LANE_TILE = 8 * LANES
+
+
+def lanes_fit(shape, dtype) -> bool:
+    """Whether a spinor of ``shape`` and ``dtype`` has a lane-dense view:
+    complex64, with its float32 elements filling whole ``(8, 128)``
+    tiles."""
+    return (jnp.dtype(dtype) == jnp.dtype(jnp.complex64)
+            and (2 * math.prod(shape)) % LANE_TILE == 0)
+
+
+def spinor_lanes(psi) -> np.ndarray:
+    """The ``(rows, 128)`` float32 view of a host complex64 spinor's own
+    bytes (no copy; see :func:`lanes_fit`)."""
+    return np.asarray(psi).view(np.float32).reshape(-1, LANES)
+
+
+def spinor_from_lanes(lanes, shape) -> np.ndarray:
+    """Inverse of :func:`spinor_lanes`: the complex64 spinor of ``shape``
+    whose bytes are the host array ``lanes`` (no copy)."""
+    return np.asarray(lanes).view(np.complex64).reshape(shape)
+
+
+def planar_from_lanes(lanes, shape, dtype=jnp.float32):
+    """Lane-dense view of a complex spinor of ``shape`` -> its planar
+    vector: :func:`spinor_to_planar` of that spinor, traceable."""
+    *batch, T, Z, Y, Xh = shape[:-2]
+    arr = lanes.reshape(*batch, T, Z, Y, Xh, SPINOR_COMPS)
+    return jnp.moveaxis(arr, (-3, -2), (-2, -1)).astype(dtype)
+
+
+def lanes_from_planar(p):
+    """Planar vector -> the lane-dense view of
+    :func:`spinor_from_planar`'s complex64 spinor, traceable."""
+    arr = jnp.moveaxis(p.astype(jnp.float32), (-2, -1), (-3, -2))
+    return arr.reshape(-1, LANES)
 
 
 def gamma5_planar(p: jnp.ndarray) -> jnp.ndarray:

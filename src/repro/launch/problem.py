@@ -6,8 +6,9 @@ of 4096 bytes, so at 32x32x32x64 the complex gauge field alone would take
 34,359,738,368 B of a 16 GB v5e.  The planar layout the kernels iterate
 on does fit.  So the gauge field and the sources are made on the host
 CPU, the operator is bound there and moved to the accelerator in its
-planar form, and :class:`repro.api.SolveSession` encodes host sources on
-the host and sends only native vectors to the chip
+planar form, and :class:`repro.api.SolveSession` sends host sources to
+the chip as lane-dense views of their bytes, which tile unpadded, or as
+native vectors encoded on the host
 (:meth:`repro.api.WilsonMatrix.encode`).  The true residual is checked
 on the host by the plain full-system operator of ``repro.core.wilson``,
 independent of the backend under test.
